@@ -11,13 +11,16 @@ Rows, one per device count in {1, 2, 4}:
       live), ``fps`` from the load trace, and ``logits_parity`` against the
       devices1 run.
 
-Both sections must see 4 host devices, and the device-count flag only acts
-before the first jax import — so the parent harnesses
-(``benchmarks/run.py`` for train, ``benchmarks/serve_load.py`` for serve)
-re-exec this module via ``rows_subprocess`` with
-``repro.dist.host_device_env(4)`` plus the same intra-op pinning the
-serve/threaded section uses (lanes should map onto execution units, not
-fight XLA's thread pool).  On a multi-core runner fps rises with the device
+The parent harnesses (``benchmarks/run.py`` for train,
+``benchmarks/serve_load.py`` for serve) call ``rows_for``.  On an
+accelerator host the section runs in the parent's own process over
+``jax.devices()`` (device counts 1, 2, 4 up to what is attached): a chip
+belongs to one process, and the parent already holds it.  On a CPU host it
+re-execs this module via ``rows_subprocess`` with
+``repro.dist.host_device_env(4)`` — the fake-device flag only acts before
+the first jax import — plus the same intra-op pinning the serve/threaded
+section uses (lanes should map onto execution units, not fight XLA's
+thread pool).  On a multi-core runner fps rises with the device
 count (the CI BENCH gate asserts fps@4 > fps@1); on a single-core container
 the sharded rows mostly measure dispatch overhead — see docs/dist.md.
 """
@@ -49,14 +52,18 @@ def _cfg(quick: bool):
     return cfg
 
 
-def _require_devices() -> None:
+def _device_counts():
+    """The mesh sizes of ``DEVICES`` this process can build.  A CPU host
+    must see all of them (the fake-device child); an accelerator host runs
+    what is attached."""
     import jax
-    need = max(DEVICES)
-    if jax.device_count() < need:
+    have = jax.device_count()
+    if jax.default_backend() == "cpu" and have < max(DEVICES):
         raise RuntimeError(
-            f"bench_dist needs {need} devices but sees "
-            f"{jax.device_count()}; run via rows_subprocess / "
-            f"repro.dist.host_device_env({need})")
+            f"bench_dist needs {max(DEVICES)} devices but sees {have}; "
+            f"run via rows_subprocess / "
+            f"repro.dist.host_device_env({max(DEVICES)})")
+    return tuple(n for n in DEVICES if n <= have)
 
 
 def _eq_tree(a, b) -> bool:
@@ -69,7 +76,7 @@ def train_rows(quick: bool):
     """us/step + throughput of the sharded train step per device count,
     with the bit-parity acceptance flag inline."""
     from repro import api
-    _require_devices()
+    counts = _device_counts()
     cfg = _cfg(quick)
     batch = 8 if quick else 32
     steps = 3 if quick else 10
@@ -79,7 +86,7 @@ def train_rows(quick: bool):
     y = (np.arange(batch) % 10).astype(np.int32)
 
     rows, fps1, params1 = [], None, None
-    for n in DEVICES:
+    for n in counts:
         sess = api.Session(
             cfg, api.TrainSpec(backend="batched", mesh={"data": n}), seed=0)
         sess.train_step(x, y)              # compile outside the timed region
@@ -107,7 +114,7 @@ def serve_rows(quick: bool):
     """Threaded-engine throughput per device count with lanes pinned to
     mesh devices, plus logits parity against the devices1 run."""
     from repro import api
-    _require_devices()
+    counts = _device_counts()
     cfg = _cfg(quick)
     n_req = 32 if quick else 128
     lanes, max_batch = 4, 8
@@ -116,7 +123,7 @@ def serve_rows(quick: bool):
                         dtype=np.float32)
 
     rows, fps1, logits1 = [], None, None
-    for n in DEVICES:
+    for n in counts:
         sess = api.Session(
             cfg, api.ServeSpec(backend="batched", mesh={"data": n},
                                num_lanes=lanes, threaded=True,
@@ -155,15 +162,26 @@ def run(section: str, quick: bool = True):
     raise ValueError(f"unknown bench_dist section {section!r}")
 
 
+def rows_for(section: str, quick: bool):
+    """The section's rows, run where the devices are: in this process on an
+    accelerator host, in a fake-device CPU child otherwise."""
+    import jax
+    if jax.default_backend() != "cpu":
+        return run(section, quick)
+    return rows_subprocess(section, quick)
+
+
 def rows_subprocess(section: str, quick: bool):
-    """Parent end: re-exec this module with 4 fake host devices + intra-op
-    pinning and parse the JSON row list off the last stdout line."""
+    """Parent end (CPU hosts only): re-exec this module with 4 fake host
+    devices + intra-op pinning and parse the JSON row list off the last
+    stdout line."""
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     if src not in sys.path:                # parent may run without
         sys.path.insert(0, src)            # PYTHONPATH=src
     from repro.dist.mesh import host_device_env
     env = host_device_env(max(DEVICES), extra_flags=DIST_XLA_FLAGS)
+    env["JAX_PLATFORMS"] = "cpu"           # the fake devices are CPU ones
     env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
                          if env.get("PYTHONPATH") else src)
     cmd = [sys.executable, "-m", "benchmarks.bench_dist",
@@ -174,17 +192,19 @@ def rows_subprocess(section: str, quick: bool):
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import configure_compile_cache
+    configure_compile_cache()
     quick = "--quick" in sys.argv
     if "--section" in sys.argv:
         section = sys.argv[sys.argv.index("--section") + 1]
         rows = run(section, quick=quick)
         print(json.dumps(rows))            # parsed by the parent process
         return
-    # standalone: run both sections through the subprocess path and print
-    # CSV (artifact files are owned by run.py / serve_load.py)
+    # standalone: run both sections where the devices are and print CSV
+    # (artifact files are owned by run.py / serve_load.py)
     print("name,us_per_call,derived")
     for section in ("train", "serve"):
-        for r in rows_subprocess(section, quick):
+        for r in rows_for(section, quick):
             print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}",
                   flush=True)
 

@@ -9,6 +9,10 @@ Modes:
                      ``BENCH_kernels.json`` (name -> us_per_call/derived)
                      so successive PRs accumulate a perf trajectory
                      (consumed by scripts/smoke.sh).
+
+A section that raises is reported as a ``<section>/ERROR`` row and the
+other sections still run, but the harness then exits non-zero and writes
+no artifact.
 """
 from __future__ import annotations
 
@@ -39,13 +43,16 @@ def _roofline_rows():
 def main() -> None:
     quick = "--quick" in sys.argv
     full = "--full" in sys.argv
+    from repro.runtime.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from benchmarks import bench_dist, bench_kernels
     if quick:
         sections = [
             ("kernels", lambda: bench_kernels.run()),
-            # sharded train rows run in a subprocess with 4 fake host
-            # devices (the device-count flag must precede jax init)
-            ("dist", lambda: bench_dist.rows_subprocess("train", True)),
+            # sharded train rows: in process on an accelerator host, in a
+            # child with 4 fake host devices on a CPU one (the
+            # device-count flag must precede jax init)
+            ("dist", lambda: bench_dist.rows_for("train", True)),
         ]
     else:
         from benchmarks import (fig6_aprc, fig7_balance, table1_throughput,
@@ -56,10 +63,10 @@ def main() -> None:
             ("table1", lambda: table1_throughput.run(quick=not full)),
             ("table2", lambda: table2_resources.run()),
             ("kernels", lambda: bench_kernels.run()),
-            ("dist", lambda: bench_dist.rows_subprocess("train", not full)),
+            ("dist", lambda: bench_dist.rows_for("train", not full)),
             ("roofline", _roofline_rows),
         ]
-    collected = []
+    collected, failed = [], []
     print("name,us_per_call,derived")
     for tag, fn in sections:
         try:
@@ -67,8 +74,12 @@ def main() -> None:
                 collected.append(r)
                 print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}",
                       flush=True)
-        except Exception as e:  # noqa: BLE001 — report, keep harness alive
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
+            failed.append(tag)
             print(f"{tag}/ERROR,0.0,{type(e).__name__}:{e}", flush=True)
+    if failed:
+        print(f"# failed sections: {','.join(failed)}", file=sys.stderr)
+        sys.exit(1)
     if quick:
         payload = {r["name"]: {"us_per_call": round(r["us_per_call"], 1),
                                "derived": r["derived"]}

@@ -593,8 +593,9 @@ def threaded_rows_subprocess(quick: bool):
     """Run the threaded section in its own interpreter with XLA pinned to
     one intra-op thread (flags are frozen at first use, and this process's
     other sections must stay on the default — historically tracked —
-    threading config)."""
+    threading config).  CPU hosts only: the child is pinned to the CPU."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
                         + THREADED_XLA_FLAGS).strip()
     src = os.path.join(os.path.dirname(os.path.dirname(
@@ -627,15 +628,23 @@ def run(quick: bool = True, section: str = "all"):
     rows += throughput_rows(params, cfg, quick)
     rows += chunked_rows(params, cfg, quick)
     rows += obs_rows(params, cfg, quick)
-    rows += threaded_rows_subprocess(quick)
-    # sharded serving (repro.dist): lanes pinned to mesh devices, run in a
-    # bench_dist subprocess that sees 4 fake host devices
+    if jax.default_backend() == "cpu":
+        rows += threaded_rows_subprocess(quick)
+    else:
+        # a chip belongs to one process, and this one holds it: the pinned
+        # intra-op threading of the child is a CPU-backend concern anyway
+        rows += run(quick=quick, section="threaded")
+    # sharded serving (repro.dist): lanes pinned to mesh devices — in
+    # process on an accelerator host, in a bench_dist child that sees 4
+    # fake host devices on a CPU one
     from benchmarks import bench_dist
-    rows += bench_dist.rows_subprocess("serve", quick)
+    rows += bench_dist.rows_for("serve", quick)
     return rows
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import configure_compile_cache
+    configure_compile_cache()
     quick = "--quick" in sys.argv
     if "--section" in sys.argv:
         section = sys.argv[sys.argv.index("--section") + 1]

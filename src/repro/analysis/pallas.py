@@ -9,7 +9,17 @@ drifts):
 2. every BlockSpec block-shape rank equals the index-map's returned
    tuple arity (block coordinates are per-dimension);
 3. statically-provable block dims divide the (padded) array dims they
-   tile — ``block_rows`` must divide ``e_h_pad`` etc.
+   tile — ``block_rows`` must divide ``e_h_pad`` etc.;
+4. a block shape that uses ``pl.Element`` (element-offset indexing, the
+   halo reads) uses it on every non-squeezed dim — Mosaic refuses a mix
+   ("All block dimensions must be Elements or none of them").
+
+Block dims it knows: ``pl.Element(n)`` (an element-offset window: the
+index map returns element offsets, so divisibility does not apply),
+``pl.squeezed`` / ``pl.Squeezed()`` / ``None`` (a size-1 dim the kernel
+does not see), and plain sizes.  A spec without a block shape — e.g. a
+whole-array ``pl.BlockSpec(memory_space=pltpu.SMEM)`` skip table — has
+nothing to check but still counts as an operand.
 
 The checker resolves names through simple same-function assignments
 (``seq_spec = pl.BlockSpec(...)`` then ``in_specs=[seq_spec, ...]``,
@@ -122,6 +132,15 @@ def _is_call_to(node: Optional[ast.expr], attr: str) -> bool:
                      and node.func.id == attr)))
 
 
+def _is_squeezed(node: ast.expr) -> bool:
+    """``pl.squeezed``, ``pl.Squeezed()`` or ``None`` as a block dim."""
+    if isinstance(node, ast.Constant) and node.value is None:
+        return True
+    if isinstance(node, ast.Attribute) and node.attr == "squeezed":
+        return True
+    return _is_call_to(node, "Squeezed")
+
+
 def _lambda_info(node: Optional[ast.expr]) -> Optional[Tuple[int, int]]:
     """(param arity, returned tuple arity) of an index-map lambda."""
     if not isinstance(node, ast.Lambda):
@@ -139,7 +158,8 @@ def _divides(block: Optional[ast.expr], dim: Optional[ast.expr],
     dim = env.resolve(dim)
     if block is None or dim is None:
         return None
-    if isinstance(block, ast.Constant) and block.value == 1:
+    if _is_squeezed(block) or (isinstance(block, ast.Constant)
+                               and block.value == 1):
         return True
     if (isinstance(block, ast.Constant) and isinstance(dim, ast.Constant)
             and isinstance(block.value, int) and isinstance(dim.value, int)):
@@ -240,6 +260,14 @@ class PallasConsistencyRule(Rule):
             block_dims: Optional[List[ast.expr]] = None
             if isinstance(block, (ast.Tuple, ast.List)):
                 block_dims = list(block.elts)
+                dims = [env.resolve(d) for d in block_dims]
+                kinds = {_is_call_to(d, "Element") for d in dims
+                         if d is not None and not _is_squeezed(d)}
+                if kinds == {True, False}:
+                    yield sf.finding(
+                        self.name, spec,
+                        f"{label}: block shape mixes pl.Element and plain "
+                        f"dims (Mosaic needs Element on every dim or none)")
             spec_ranks[label] = block_dims
             lam = _lambda_info(env.resolve(index_map))
             if lam is not None:
@@ -267,9 +295,11 @@ class PallasConsistencyRule(Rule):
                     continue
                 assert isinstance(spec, ast.Call)
                 assert isinstance(shape_call, ast.Call)
-                if _keyword(spec, "indexing_mode") is not None:
-                    continue  # unblocked specs index elements, not blocks
                 block = env.resolve(spec.args[0]) if spec.args else None
+                if isinstance(block, (ast.Tuple, ast.List)) and any(
+                        _is_call_to(env.resolve(d), "Element")
+                        for d in block.elts):
+                    continue  # element-offset windows, not blocks
                 shape = env.resolve(shape_call.args[0]) \
                     if shape_call.args else None
                 if not (isinstance(block, (ast.Tuple, ast.List))

@@ -19,7 +19,7 @@ constant over ``T``, so the first-layer conv is hoisted out of the time loop
 entirely — computed once and reused for all ``T`` steps.  ``"batched"``
 stays in XLA ops (the fast CPU path); ``"pallas"`` dispatches the fused
 ``spiking_conv_lif`` kernel per layer (time loop inside the kernel, membrane
-in registers, (T,B,row-block) spike-skip table; see docs/kernels.md).
+in VMEM, (T,B,row-block) spike-skip table; see docs/kernels.md).
 
 All three backends are differentiable with the same selectable surrogate
 (``surrogate_kind`` x ``surrogate_alpha``): the time-batched paths

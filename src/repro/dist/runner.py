@@ -95,18 +95,66 @@ class MeshRunner:
         return kw
 
     # -- inference -----------------------------------------------------------
+    def _batch_pspec(self):
+        from jax.sharding import PartitionSpec
+        axes = self.ctx.axes_for("batch")
+        return PartitionSpec(tuple(axes) if axes else None)
+
     def _infer_fn(self, m: int, sample_shape: Tuple[int, ...]):
         fn = self._infer_fns.get(m)
         if fn is None:
             from repro.core.snn_model import snn_apply
             kw = self._exec_kwargs()
             cfg = self.cfg
-            bsh = self._batch_sharding((m,) + tuple(sample_shape))
-            fn = jax.jit(lambda p, x: snn_apply(p, x, cfg, **kw),
-                         in_shardings=(self._rep, bsh),
-                         out_shardings=self._rep)
+            if kw.get("backend") == "pallas":
+                fn = self._manual_infer_fn(kw)
+            else:
+                bsh = self._batch_sharding((m,) + tuple(sample_shape))
+                fn = jax.jit(lambda p, x: snn_apply(p, x, cfg, **kw),
+                             in_shardings=(self._rep, bsh),
+                             out_shardings=self._rep)
             self._infer_fns[m] = fn
         return fn
+
+    def _manual_infer_fn(self, kw: Dict[str, object]):
+        """Batch-sharded forward through ``jax.shard_map``: a Pallas TPU
+        kernel is opaque to XLA's SPMD partitioner, which would gather the
+        batch and run all of it on every device.  Each device runs its own
+        rows, one batch-1 program per row (``lax.map``): on a TPU the XLA
+        reductions around the kernels (the readout) pick their order by the
+        local batch extent, which changes with the device count.  The
+        per-row spike statistics are summed (integer-valued, so exact) and
+        psum'd; the skip fractions are averaged and pmean'd."""
+        from jax.sharding import PartitionSpec
+        from repro.core.snn_model import SNNOutputs, snn_apply
+        cfg = self.cfg
+        axes = tuple(self.ctx.axes_for("batch"))
+        batch = self._batch_pspec()
+
+        def body(p, x):
+            rows = jax.lax.map(lambda x1: snn_apply(p, x1[None], cfg, **kw),
+                               x)
+
+            def total(ts):
+                ts = tuple(t.sum(axis=0) for t in ts)
+                return tuple(jax.lax.psum(t, axes) for t in ts) if axes \
+                    else ts
+            skips = tuple(t.mean(axis=0) for t in rows.skip_fractions)
+            return SNNOutputs(
+                logits=rows.logits[:, 0],
+                spike_counts=total(rows.spike_counts),
+                spike_totals=total(rows.spike_totals),
+                timestep_counts=total(rows.timestep_counts),
+                skip_fractions=tuple(jax.lax.pmean(t, axes) for t in skips)
+                if axes else skips)
+
+        rep = PartitionSpec()
+        return jax.jit(jax.shard_map(
+            body, mesh=self.dm.mesh, in_specs=(rep, batch),
+            out_specs=SNNOutputs(logits=batch, spike_counts=rep,
+                                 spike_totals=rep, timestep_counts=rep,
+                                 skip_fractions=rep),
+            check_vma=False))
 
     def infer(self, params, frames: np.ndarray, *,
               pad_to: Optional[int] = None):
@@ -126,30 +174,33 @@ class MeshRunner:
         return out._replace(logits=np.asarray(out.logits)[:n])
 
     # -- training ------------------------------------------------------------
+    def _manual_grads(self) -> bool:
+        return self._exec_kwargs().get("backend", "ref") in ("ref", "pallas")
+
     def _grad_fn(self, m: int, sample_shape: Tuple[int, ...]):
         fn = self._grad_fns.get(m)
         if fn is None:
             from repro.core.snn_train import make_grad_rows_fn
-            if self._exec_kwargs().get("backend", "ref") == "ref":
+            if self._manual_grads():
                 # the "ref" timestep-outer scan trips an XLA SPMD
                 # partitioner RET_CHECK (reshape element-count mismatch)
-                # when the vmapped per-example grad is auto-partitioned;
+                # when the vmapped per-example grad is auto-partitioned,
+                # and a Pallas TPU kernel cannot be partitioned at all (the
+                # partitioner would run the whole batch on every device);
                 # shard_map partitions the batch manually instead.  The
                 # body must be sequential (lax.map of a batch-1 program):
                 # a vmapped body's last-ulp arithmetic depends on the
                 # *local* batch extent, which varies with device count —
                 # the batch-1 body is identical everywhere, keeping rows
                 # bit-exact across shardings
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec
                 rows_fn = make_grad_rows_fn(self.cfg, spec=self.spec,
                                             sequential=True)
-                axes = self.ctx.axes_for("batch")
-                batch = PartitionSpec(tuple(axes) if axes else None)
-                rows_fn = shard_map(
+                batch = self._batch_pspec()
+                rows_fn = jax.shard_map(
                     rows_fn, mesh=self.dm.mesh,
                     in_specs=(PartitionSpec(), batch, batch),
-                    out_specs=batch, check_rep=False)
+                    out_specs=batch, check_vma=False)
                 fn = jax.jit(rows_fn)
             else:
                 rows_fn = make_grad_rows_fn(self.cfg, spec=self.spec)
@@ -176,8 +227,17 @@ class MeshRunner:
             x = np.concatenate(
                 [x, np.zeros((m - n,) + x.shape[1:], x.dtype)], axis=0)
             y = np.concatenate([y, np.zeros((m - n,), y.dtype)], axis=0)
-        loss_rows, grad_rows = self._grad_fn(m, x.shape[1:])(params, x, y)
-        loss_rows = np.asarray(loss_rows)[:n]
+        # the shard_map path runs one row per device per call, so the local
+        # program — shapes and buffer layouts included — is the same at
+        # every device count: on a TPU, the layout XLA gave a longer row
+        # loop's stacked outputs changed the weight-gradient arithmetic
+        step = self._batch_div if self._manual_grads() else m
+        parts = [self._grad_fn(step, x.shape[1:])(
+            params, x[i:i + step], y[i:i + step]) for i in range(0, m, step)]
+        loss_rows = np.concatenate([np.asarray(p[0]) for p in parts])[:n]
+        grad_rows = jax.tree.map(
+            lambda *rows: np.concatenate([np.asarray(r) for r in rows]),
+            *[p[1] for p in parts])
         loss = float(loss_rows.mean(dtype=np.float32))
         lr = float(getattr(self.spec, "lr", 1e-3))
         mv = float(getattr(self.spec, "momentum", 0.9))

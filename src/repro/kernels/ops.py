@@ -1,7 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto: Pallas interpret mode on CPU (this
-container), compiled Mosaic on real TPU.
+``interpret`` is resolved here and nowhere else: ``None`` (the default)
+means compiled Mosaic when JAX's default backend is a TPU and Pallas
+interpret mode otherwise (CPU validation).  The first interpret-mode
+resolution logs a warning, so a run that expected the chip cannot drop
+to the interpreter unnoticed; the raw kernel entries take ``interpret``
+as a required keyword.
 
 All wrappers are **differentiable**: ``spiking_conv`` and
 ``spiking_conv_lif`` carry ``jax.custom_vjp`` rules (surrogate BPTT for the
@@ -36,7 +40,20 @@ __all__ = ["spiking_conv", "lif_fused", "spiking_conv_lif",
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True (Pallas interpret mode) unless JAX's default backend is a TPU."""
+    backend = jax.default_backend()
+    if backend != "tpu":
+        _warn_interpret(backend)
+    return backend != "tpu"
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_interpret(backend: str) -> None:
+    # once per backend and process: interpret mode is a validation path
+    from repro.obs.log import get_logger
+    get_logger("kernels").warning(
+        "default backend is %r, not tpu: Pallas kernels run in interpret "
+        "mode", backend)
 
 
 def _default_bwd(interpret: bool) -> str:
@@ -47,10 +64,10 @@ def _default_bwd(interpret: bool) -> str:
 
 
 class _ConvOpts(NamedTuple):
+    interpret: bool
     aprc: bool = True
     block_rows: int = 8
     num_groups: int = 4
-    interpret: bool = True
     bwd: str = "xla"
 
 
@@ -201,9 +218,9 @@ def spiking_conv_lif_chunked(
     segments (``v_final`` of one call is ``v0`` of the next).
 
     Bit-identical to the single whole-T ``spiking_conv_lif`` call for
-    every partition of T: the kernel's in-block ``fori_loop`` is strictly
-    sequential per element, so a chunk boundary only materializes the
-    carry it would have held in registers.  Differentiable — each segment
+    every partition of T: the kernel's T grid axis is strictly sequential
+    per element, so a chunk boundary only materializes the carry it would
+    have held in VMEM scratch.  Differentiable — each segment
     goes through ``spiking_conv_lif_train``'s custom_vjp and BPTT chains
     across segments through the carried membrane.
     """

@@ -3,13 +3,13 @@
 TPU-native adaptation of Skydiver's event-driven SPE array (DESIGN §2/§6):
 
   * implicit GEMM: for each filter tap (dy, dx), an MXU matmul
-        (rows x W_out, Cin) @ (Cin, Cout_group)
+        (Cout_group, Cin) @ (rows x W_out, Cin)^T
     accumulates dV — the adder-tree of the paper's SPE cluster becomes the
     MXU systolic reduction over Cin.
   * lane granularity: grid axis 2 walks CBWS-permuted *output-channel
     groups* (the "filter-based SPE clusters"); grid axis 1 walks row blocks
     (the "4 streams" of a SPE, generalized).
-  * spatio-temporal skip: a scalar-prefetch table ``counts[b, i]`` holds the
+  * spatio-temporal skip: an SMEM table ``counts[b, i]`` holds the
     spike population of the input rows feeding row-block i of image b
     (b folds **batch x timestep** — callers running the time-batched layer
     pipeline fold ``(T, B) -> T*B`` so the skip table covers the full
@@ -20,10 +20,9 @@ TPU-native adaptation of Skydiver's event-driven SPE array (DESIGN §2/§6):
 Memory-traffic model (per grid cell, halo BlockSpec):
 
   * input block: ``(block_rows + R - 1) x W_pad x Cin`` — only the halo
-    rows feeding this output row-block are loaded (``pl.unblocked``
-    element-offset indexing).  Before this fix every one of the
-    ``n_blocks x num_groups`` cells re-read the entire padded image, an
-    ``n_blocks x num_groups``-fold overread; now total input traffic is
+    rows feeding this output row-block are loaded (``pl.Element`` on every
+    block dim: the index map returns element offsets, row-block i starts at
+    row ``i * block_rows``), so total input traffic is
     ``~(1 + (R-1)/block_rows) x num_groups`` image reads.
   * weights: one ``(R, R, Cin, Cout/num_groups)`` tap block per cell.
   * output: each dV element is written exactly once.
@@ -31,9 +30,23 @@ Memory-traffic model (per grid cell, halo BlockSpec):
 Weights arrive already CBWS-permuted (see core.scheduler); the kernel sees
 only equal-size contiguous channel groups.
 
-Block sizing: Cout_group should be a multiple of 128 (MXU lanes) and
-rows*W_out a multiple of 8 (sublanes) on real TPU; the kernel itself is
-shape-generic and is validated in interpret mode on CPU.
+TPU (Mosaic) layout rules the specs follow:
+
+  * the channel-group axis is a *leading* array axis of weights, bias and
+    outputs, so the last two block dims are always whole array dims —
+    Mosaic requires them to be whole or multiples of (8, 128), and a
+    ``Cout/G``-wide slice of a ``Cout``-wide last dim is neither;
+  * each tap computes dV transposed, ``(Cout/G, rows x W_out)``, and the
+    outputs keep that orientation (``to_block_layout``): pixels fill the
+    128 lanes, the few channels of a group the sublanes.  A ``Cout/G``-wide
+    last dim would pad to 128 lanes in HBM (32x at ``Cout/G = 4``), which
+    did not fit snn-seg in a 16 GB v5e;
+  * the skip table lives in SMEM (scalar reads by grid position);
+  * tap tiles are static slices of the halo block.
+
+The input spike train keeps the canonical (..., W, Cin) layout (Cin pads
+to 128 lanes), and block sizing is not tuned; the kernels are also
+validated in interpret mode.
 
 The BlockSpec contracts at each ``pl.pallas_call`` site here (index-map
 arity vs grid rank, block rank vs index-map return arity, block dims
@@ -49,10 +62,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["spiking_conv_kernel", "spiking_conv_pallas", "row_block_counts",
            "skip_table_fraction", "conv_grad_input_xla",
-           "conv_grad_input_pallas", "conv_grad_weights_xla", "conv_pads"]
+           "conv_grad_input_pallas", "conv_grad_weights_xla", "conv_pads",
+           "to_block_layout", "from_block_layout", "group_taps", "tap_gemm"]
 
 
 def conv_pads(r: int, aprc: bool) -> tuple:
@@ -63,35 +78,81 @@ def conv_pads(r: int, aprc: bool) -> tuple:
     return lo, r - 1 - lo
 
 
-def _make_kernel(r: int, block_rows: int, w_out: int):
+def to_block_layout(a: jax.Array, groups: int, block_rows: int) -> jax.Array:
+    """Canonical (..., H_pad, W, C) -> kernel layout
+    (G, ..., n_blocks, C/G, block_rows * W).
+
+    Channel group g is leading slice g, and within a row block each channel
+    is one lane-major row of ``block_rows * W`` pixels — the orientation of
+    ``tap_gemm``'s result, so the last two block dims are whole array dims
+    and the pixels, not the few channels of a group, fill the lanes."""
+    *lead, hp, w, c = a.shape
+    n = len(lead)
+    a = a.reshape(tuple(lead) + (hp // block_rows, block_rows, w, groups,
+                                 c // groups))
+    # (lead..., nb, br, w, G, cb) -> (G, lead..., nb, cb, br, w)
+    a = a.transpose((n + 3,) + tuple(range(n)) + (n, n + 4, n + 1, n + 2))
+    return a.reshape((groups,) + tuple(lead)
+                     + (hp // block_rows, c // groups, block_rows * w))
+
+
+def from_block_layout(a: jax.Array, w: int) -> jax.Array:
+    """Inverse of ``to_block_layout``: -> (..., n_blocks * block_rows, W, C)."""
+    g, *lead, nb, cb, m = a.shape
+    n = len(lead)
+    a = a.reshape((g,) + tuple(lead) + (nb, cb, m // w, w))
+    # (G, lead..., nb, cb, br, w) -> (lead..., nb, br, w, G, cb)
+    a = a.transpose(tuple(range(1, n + 1)) + (n + 1, n + 3, n + 4, 0, n + 2))
+    return a.reshape(tuple(lead) + (nb * (m // w), w, g * cb))
+
+
+def group_taps(w: jax.Array, groups: int) -> jax.Array:
+    """(R, R, Cin, Cout) taps -> per-group transposed taps
+    (G, R, R, Cout/G, Cin)."""
+    r, _, cin, cout = w.shape
+    return w.reshape(r, r, cin, groups, cout // groups).transpose(3, 0, 1, 4, 2)
+
+
+def tap_gemm(x_ref, w_ref, r: int, block_rows: int, w_out: int):
+    """The R*R implicit-GEMM tap loop over one halo block.
+
+    ``x_ref``: (block_rows + R - 1, W_pad, Cin) receptive rows;  ``w_ref``:
+    (R, R, C_blk, Cin) transposed taps.  Each tap is a static slice of the
+    block and one (C_blk, Cin) x (block_rows * w_out, Cin)^T MXU matmul at
+    full f32 precision.  Returns dV as (C_blk, block_rows * w_out) f32."""
+    cin = x_ref.shape[-1]
+    m = block_rows * w_out
+    acc = jnp.zeros((w_ref.shape[-2], m), jnp.float32)
+    for dy in range(r):                                # R*R MXU matmuls
+        for dx in range(r):
+            tile = x_ref[dy:dy + block_rows, dx:dx + w_out, :]
+            acc = acc + jax.lax.dot_general(
+                w_ref[dy, dx].astype(jnp.float32),
+                tile.astype(jnp.float32).reshape(m, cin),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+    return acc
+
+
+def _make_kernel(r: int, block_rows: int, w_out: int, n_blocks: int):
     def kernel(counts_ref, x_ref, w_ref, b_ref, o_ref):
         b = pl.program_id(0)
         i = pl.program_id(1)
-        cout_blk = o_ref.shape[-1]
-        bias = b_ref[...].astype(jnp.float32)
+        count = counts_ref[b * n_blocks + i]
+        bias = b_ref[...].astype(jnp.float32)          # (Cout_blk, 1)
 
-        @pl.when(counts_ref[b, i] == 0)
+        @pl.when(count == 0)
         def _skip():
             # no spikes feed this row block: dV is bias only
             o_ref[...] = jnp.broadcast_to(
                 bias, o_ref.shape).astype(o_ref.dtype)
 
-        @pl.when(counts_ref[b, i] != 0)
+        @pl.when(count != 0)
         def _compute():
             # halo block: only the block_rows + R - 1 receptive rows
-            x = x_ref[0].astype(jnp.float32)   # (block_rows+R-1, W_pad, Cin)
-            cin = x.shape[-1]
-            acc = jnp.zeros((block_rows * w_out, cout_blk), jnp.float32)
-            for dy in range(r):                        # R*R MXU matmuls
-                for dx in range(r):
-                    tile = jax.lax.dynamic_slice(
-                        x, (dy, dx, 0), (block_rows, w_out, cin))
-                    tap = w_ref[dy, dx].astype(jnp.float32)   # (Cin, Cout_blk)
-                    acc = acc + jnp.dot(
-                        tile.reshape(block_rows * w_out, cin), tap,
-                        preferred_element_type=jnp.float32)
-            out = acc.reshape(block_rows, w_out, cout_blk) + bias
-            o_ref[...] = out[None].astype(o_ref.dtype)
+            out = tap_gemm(x_ref, w_ref, r, block_rows, w_out) + bias
+            o_ref[...] = out.astype(o_ref.dtype)
 
     return kernel
 
@@ -154,7 +215,7 @@ def spiking_conv_pallas(
     aprc: bool = True,
     block_rows: int = 8,
     num_groups: int = 4,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns dV: (B, E_h, E_w, Cout); E = H+R-1 (APRC) or H (same-pad)."""
     B, H, W, Cin = spikes.shape
@@ -177,29 +238,38 @@ def spiking_conv_pallas(
     x = jnp.zeros((B, h_pad, w_pad, Cin), spikes.dtype)
     x = jax.lax.dynamic_update_slice(x, spikes, (0, pad_lo, pad_lo, 0))
 
-    counts = row_block_counts(x, R, block_rows, n_blocks)
+    # flat (B * n_blocks,) int32 skip table, whole in SMEM
+    counts = row_block_counts(x, R, block_rows, n_blocks).reshape(-1)
     halo_rows = block_rows + R - 1
 
-    kernel = _make_kernel(R, block_rows, e_w)
+    kernel = _make_kernel(R, block_rows, e_w, n_blocks)
     out = pl.pallas_call(
         kernel,
         grid=(B, n_blocks, num_groups),
         in_specs=[
-            pl.BlockSpec((B, n_blocks), lambda b, i, g: (0, 0)),      # counts
-            # halo input block: element offsets (pl.unblocked) — row-block i
+            pl.BlockSpec(memory_space=pltpu.SMEM),                  # counts
+            # halo input block: element offsets (pl.Element) — row-block i
             # reads exactly its block_rows + R - 1 receptive rows
-            pl.BlockSpec((1, halo_rows, w_pad, Cin),
-                         lambda b, i, g: (b, i * block_rows, 0, 0),
-                         indexing_mode=pl.unblocked),
-            pl.BlockSpec((R, R, Cin, cout_blk), lambda b, i, g: (0, 0, 0, g)),
-            pl.BlockSpec((cout_blk,), lambda b, i, g: (g,)),
+            pl.BlockSpec((pl.squeezed, pl.Element(halo_rows),
+                          pl.Element(w_pad), pl.Element(Cin)),
+                         lambda b, i, g: (b, i * block_rows, 0, 0)),
+            pl.BlockSpec((pl.squeezed, R, R, cout_blk, Cin),
+                         lambda b, i, g: (g, 0, 0, 0, 0)),
+            pl.BlockSpec((pl.squeezed, cout_blk, 1),
+                         lambda b, i, g: (g, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_rows, e_w, cout_blk),
-                               lambda b, i, g: (b, i, 0, g)),
-        out_shape=jax.ShapeDtypeStruct((B, e_h_pad, e_w, Cout), spikes.dtype),
+        out_specs=pl.BlockSpec((pl.squeezed, pl.squeezed, pl.squeezed,
+                                cout_blk, block_rows * e_w),
+                               lambda b, i, g: (g, b, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (num_groups, B, n_blocks, cout_blk, block_rows * e_w),
+            spikes.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(counts, x, w, bias)
-    return out[:, :e_h]
+    )(counts, x, group_taps(w, num_groups),
+      bias.reshape(num_groups, cout_blk, 1))
+    return from_block_layout(out, e_w)[:, :e_h]
 
 
 spiking_conv_kernel = _make_kernel
@@ -243,19 +313,8 @@ def _make_grad_input_kernel(r: int, block_rows: int, w_out: int):
     structure as the forward kernel, no skip table (the cotangent is
     dense) and no bias."""
     def kernel(g_ref, wt_ref, o_ref):
-        cin_blk = o_ref.shape[-1]
-        g = g_ref[0].astype(jnp.float32)     # (block_rows+R-1, W_pad, Cout)
-        cout = g.shape[-1]
-        acc = jnp.zeros((block_rows * w_out, cin_blk), jnp.float32)
-        for dy in range(r):                  # R*R MXU matmuls
-            for dx in range(r):
-                tile = jax.lax.dynamic_slice(
-                    g, (dy, dx, 0), (block_rows, w_out, cout))
-                tap = wt_ref[dy, dx].astype(jnp.float32)  # (Cout, Cin_blk)
-                acc = acc + jnp.dot(
-                    tile.reshape(block_rows * w_out, cout), tap,
-                    preferred_element_type=jnp.float32)
-        o_ref[...] = acc.reshape(block_rows, w_out, cin_blk)[None].astype(
+        # g_ref: (block_rows+R-1, W_pad, Cout);  wt_ref: (R, R, Cin_blk, Cout)
+        o_ref[...] = tap_gemm(g_ref, wt_ref, r, block_rows, w_out).astype(
             o_ref.dtype)
 
     return kernel
@@ -270,7 +329,7 @@ def conv_grad_input_pallas(
     aprc: bool = True,
     block_rows: int = 8,
     num_groups: int = 1,     # lanes over Cin (the *output* channels here)
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Pallas transposed-tap backward kernel: dL/d(input), (N, H, W, Cin)."""
     N, e_h, e_w, Cout = dz.shape
@@ -287,7 +346,8 @@ def conv_grad_input_pallas(
     g = jnp.zeros((N, h_pad, w_pad, Cout), jnp.float32)
     g = jax.lax.dynamic_update_slice(
         g, dz.astype(jnp.float32), (0, R - 1 - lo, R - 1 - lo, 0))
-    wt = _transposed_taps(w).astype(jnp.float32)
+    wt = group_taps(_transposed_taps(w).astype(jnp.float32),
+                    num_groups)                  # (G, R, R, Cin/G, Cout)
     halo_rows = block_rows + R - 1
 
     kernel = _make_grad_input_kernel(R, block_rows, W)
@@ -295,18 +355,22 @@ def conv_grad_input_pallas(
         kernel,
         grid=(N, n_blocks, num_groups),
         in_specs=[
-            pl.BlockSpec((1, halo_rows, w_pad, Cout),
-                         lambda b, i, g_: (b, i * block_rows, 0, 0),
-                         indexing_mode=pl.unblocked),
-            pl.BlockSpec((R, R, Cout, cin_blk),
-                         lambda b, i, g_: (0, 0, 0, g_)),
+            pl.BlockSpec((pl.squeezed, pl.Element(halo_rows),
+                          pl.Element(w_pad), pl.Element(Cout)),
+                         lambda b, i, g_: (b, i * block_rows, 0, 0)),
+            pl.BlockSpec((pl.squeezed, R, R, cin_blk, Cout),
+                         lambda b, i, g_: (g_, 0, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_rows, W, cin_blk),
-                               lambda b, i, g_: (b, i, 0, g_)),
-        out_shape=jax.ShapeDtypeStruct((N, h_out_pad, W, Cin), jnp.float32),
+        out_specs=pl.BlockSpec((pl.squeezed, pl.squeezed, pl.squeezed,
+                                cin_blk, block_rows * W),
+                               lambda b, i, g_: (g_, b, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (num_groups, N, n_blocks, cin_blk, block_rows * W), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(g, wt)
-    return out[:, :H]
+    return from_block_layout(out, W)[:, :H]
 
 
 def conv_grad_weights_xla(x: jax.Array, dz: jax.Array, *, aprc: bool,
@@ -316,7 +380,8 @@ def conv_grad_weights_xla(x: jax.Array, dz: jax.Array, *, aprc: bool,
     x: (N, H, W, Cin) forward input;  dz: (N, E_h, E_w, Cout).
     dw[dy,dx,ci,co] = sum_{n,y,x} x_pad[n, y+dy, x+dx, ci] * dz[n, y, x, co]
     — one (Cin, N*E*E') @ (N*E*E', Cout) matmul per tap, the exact
-    transpose of the forward implicit GEMM.
+    transpose of the forward implicit GEMM, at full f32 precision like the
+    forward taps (XLA's default f32 matmul on a TPU rounds through bf16).
     """
     lo, hi = conv_pads(r, aprc)
     n, e_h, e_w, cout = dz.shape
@@ -330,7 +395,8 @@ def conv_grad_weights_xla(x: jax.Array, dz: jax.Array, *, aprc: bool,
         for dx in range(r):
             tile = jax.lax.dynamic_slice(
                 xp, (0, dy, dx, 0), (n, e_h, e_w, cin))
-            cols.append(tile.reshape(n * e_h * e_w, cin).T @ gz)
+            cols.append(jnp.dot(tile.reshape(n * e_h * e_w, cin).T, gz,
+                                precision=jax.lax.Precision.HIGHEST))
         rows.append(jnp.stack(cols))
     dw = jnp.stack(rows)                       # (R, R, Cin, Cout)
     db = dz.astype(jnp.float32).sum(axis=(0, 1, 2))

@@ -2,8 +2,9 @@
 
 One kernel runs a whole conv layer for **all T timesteps**: the implicit-GEMM
 tap loop of ``spiking_conv.py`` and the LIF integrate/fire/reset of ``lif.py``
-are fused, and the timestep loop lives *inside* the kernel so the membrane
-potential never leaves registers between steps.
+are fused, and the timestep walk is the innermost (sequential) grid axis,
+so the membrane potential stays in VMEM scratch between steps and never
+round-trips through HBM.
 
 Why (memory-traffic model, per layer, T timesteps):
 
@@ -12,17 +13,21 @@ Why (memory-traffic model, per layer, T timesteps):
   dV:  T writes + T reads    never materialized in HBM
   v:   T reads + T writes    1 read (v0) + 1 write (v_T)
   s:   T writes              T writes
-  x:   T whole-image reads   T halo-block reads (pl.unblocked offsets)
+  x:   T whole-image reads   T halo-block reads (pl.Element offsets)
        per grid cell
 
 i.e. per element the HBM round trips drop from ~5T to ~T+2 — the fusion of
 Sommer et al. (arXiv 2203.12437, accumulate-into-neuron) combined with
 FireFly v2's (arXiv 2309.16158) spatiotemporal (T x B) batching.
 
-Grid: ``(B, n_row_blocks, num_groups)`` — batch x row-block x CBWS channel
-lane.  The spike-count skip table ``counts[t, b, i]`` covers the full
-spatio-temporal workload (paper Fig. 2): a timestep whose receptive rows
-carry no spikes skips all R*R matmuls and integrates bias only.
+Grid: ``(B, n_row_blocks, num_groups, T)`` — batch x row-block x CBWS
+channel lane x timestep.  Each cell holds one timestep's blocks, so VMEM
+use does not grow with T (a whole-T block overflowed VMEM at snn-seg
+widths).  The spike-count skip table ``counts[t, b, i]`` (SMEM) covers the
+full spatio-temporal workload (paper Fig. 2): a timestep whose receptive
+rows carry no spikes skips all R*R matmuls and integrates bias only.
+Outputs and membranes use the kernel layout of
+``spiking_conv.to_block_layout`` (see that module's doc for why).
 
 Sequencing caveat: the input spike train for all T must be known, so this
 kernel runs in the **layer-by-layer** (time-batched) execution order of
@@ -37,7 +42,8 @@ with an extra output — the **pre-reset membrane** ``u_t = v_{t-1} + dV_t``,
 exactly the residual the surrogate needs — and the bwd rule runs surrogate
 BPTT in the time-batched order:
 
-  1. reverse-time elementwise scan (``lif_bwd_pallas`` / XLA fallback):
+  1. reverse-time elementwise scan (``lif_bwd_pallas``, whose T grid
+     axis walks time backward / XLA fallback):
          lam_t = c_t + (g_s[t] - v_th * c_t) * sg(u_t - v_th)
          c_{t-1} = lam_t,        dv0 = lam_0
      with ``sg`` the selectable surrogate (core.surrogate.surrogate_grad)
@@ -57,8 +63,9 @@ the padded shapes, operand/spec counts) are checked statically by
 ``repro.analysis``'s pallas-consistency rule (docs/analysis.md), which
 resolves the named ``seq_spec``/``mem_spec`` assignments and the
 ``[base] + extra`` list concatenation below (``extra`` is an
-``[x] if save_u else []`` conditional) — keep spec plumbing in that
-resolvable shape.
+``[x] if save_u else []`` conditional) and knows ``pl.Element`` /
+``pl.squeezed`` block dims and whole-array SMEM specs — keep spec plumbing
+in that resolvable shape.
 """
 from __future__ import annotations
 
@@ -68,62 +75,57 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.surrogate import surrogate_grad
 from repro.kernels.spiking_conv import (conv_grad_input_pallas,
                                         conv_grad_input_xla,
                                         conv_grad_weights_xla,
-                                        row_block_counts)
+                                        from_block_layout, group_taps,
+                                        row_block_counts, tap_gemm,
+                                        to_block_layout)
 
 __all__ = ["spiking_conv_lif_pallas", "spiking_conv_lif_fwd_pallas",
            "spiking_conv_lif_train", "ConvLIFOpts", "lif_bwd_pallas",
            "lif_bwd_xla"]
 
 
-def _make_kernel(r: int, t_steps: int, block_rows: int, w_out: int,
+def _make_kernel(r: int, block_rows: int, w_out: int, n_blocks: int,
                  v_th: float, save_u: bool = False):
     def kernel(counts_ref, x_ref, w_ref, b_ref, v0_ref, s_ref, v_ref,
-               *maybe_u_ref):
+               *rest):
+        *maybe_u_ref, v_scr = rest
         b = pl.program_id(0)
         i = pl.program_id(1)
-        cout_blk = v_ref.shape[-1]
-        bias = b_ref[...].astype(jnp.float32)
-        cin = x_ref.shape[-1]
-        taps = w_ref[...].astype(jnp.float32)      # (R, R, Cin, Cout_blk)
+        t = pl.program_id(3)
+        n_batch = pl.num_programs(0)
+        bias = b_ref[...].astype(jnp.float32)      # (Cout_blk, 1)
 
-        def conv_at(t):
-            def compute():
-                # halo block for timestep t: (block_rows+R-1, W_pad, Cin)
-                x = x_ref[t, 0].astype(jnp.float32)
-                acc = jnp.zeros((block_rows * w_out, cout_blk), jnp.float32)
-                for dy in range(r):                # R*R MXU matmuls
-                    for dx in range(r):
-                        tile = jax.lax.dynamic_slice(
-                            x, (dy, dx, 0), (block_rows, w_out, cin))
-                        acc = acc + jnp.dot(
-                            tile.reshape(block_rows * w_out, cin),
-                            taps[dy, dx], preferred_element_type=jnp.float32)
-                return acc.reshape(block_rows, w_out, cout_blk) + bias
+        @pl.when(t == 0)
+        def _load_v0():
+            v_scr[...] = v0_ref[...].astype(jnp.float32)
 
-            def skip():
-                # spatio-temporal skip: no spikes feed (t, b, i) — bias only
-                return jnp.broadcast_to(bias, (block_rows, w_out, cout_blk))
+        def compute():
+            # halo block for timestep t: (block_rows+R-1, W_pad, Cin)
+            return tap_gemm(x_ref, w_ref, r, block_rows, w_out) + bias
 
-            return jax.lax.cond(counts_ref[t, b, i] == 0, skip, compute)
+        def skip():
+            # spatio-temporal skip: no spikes feed (t, b, i) — bias only
+            return jnp.broadcast_to(bias, v_scr.shape)
 
-        def step(t, v):
-            v = v + conv_at(t)                     # Eq. (1)+(2): integrate dV
-            if save_u:
-                # pre-reset membrane: the surrogate's backward residual
-                maybe_u_ref[0][t, 0] = v.astype(maybe_u_ref[0].dtype)
-            s = (v >= v_th).astype(jnp.float32)    # Eq. (3): fire
-            v = v - v_th * s                       # reset by subtraction
-            s_ref[t, 0] = s.astype(s_ref.dtype)
-            return v
+        count = counts_ref[(t * n_batch + b) * n_blocks + i]
+        v = v_scr[...] + jax.lax.cond(count == 0, skip, compute)  # Eq. (1)+(2)
+        if save_u:
+            # pre-reset membrane: the surrogate's backward residual
+            maybe_u_ref[0][...] = v.astype(maybe_u_ref[0].dtype)
+        s = (v >= v_th).astype(jnp.float32)        # Eq. (3): fire
+        v = v - v_th * s                           # reset by subtraction
+        s_ref[...] = s.astype(s_ref.dtype)
+        v_scr[...] = v
 
-        v = jax.lax.fori_loop(0, t_steps, step,
-                              v0_ref[0].astype(jnp.float32))
-        v_ref[...] = v[None].astype(v_ref.dtype)
+        @pl.when(t == pl.num_programs(3) - 1)
+        def _store_v():
+            v_ref[...] = v.astype(v_ref.dtype)
 
     return kernel
 
@@ -152,49 +154,69 @@ def _fused_call(spikes, v0, w, bias, *, v_th, aprc, block_rows, num_groups,
     x = jnp.zeros((T, B, h_pad, w_pad, Cin), spikes.dtype)
     x = jax.lax.dynamic_update_slice(x, spikes, (0, 0, pad_lo, pad_lo, 0))
 
-    # skip table over the full (T, B, row-block) spatio-temporal workload
+    # skip table over the full (T, B, row-block) spatio-temporal workload,
+    # flat (T * B * n_blocks,) int32, whole in SMEM
     counts = row_block_counts(
         x.reshape(T * B, h_pad, w_pad, Cin), R, block_rows, n_blocks
-    ).reshape(T, B, n_blocks)
+    ).reshape(-1)
 
     vp = jnp.zeros((B, e_h_pad, e_w, Cout), v0.dtype)
     vp = jax.lax.dynamic_update_slice(vp, v0, (0, 0, 0, 0))
 
-    seq_spec = pl.BlockSpec((T, 1, block_rows, e_w, cout_blk),
-                            lambda b, i, g: (0, b, i, 0, g))
-    mem_spec = pl.BlockSpec((1, block_rows, e_w, cout_blk),
-                            lambda b, i, g: (b, i, 0, g))
+    # grid (B, row-block, channel group, T): T is the innermost,
+    # sequential axis — one timestep's blocks per cell, the membrane
+    # carried across it in VMEM scratch.  Outputs and membranes are in
+    # spiking_conv.to_block_layout: (G, [T,] B, n_blocks, Cout/G, M)
+    m = block_rows * e_w
+    seq_spec = pl.BlockSpec(
+        (pl.squeezed, pl.squeezed, pl.squeezed, pl.squeezed, cout_blk, m),
+        lambda b, i, g, t: (g, t, b, i, 0, 0))
+    mem_spec = pl.BlockSpec(
+        (pl.squeezed, pl.squeezed, pl.squeezed, cout_blk, m),
+        lambda b, i, g, t: (g, b, i, 0, 0))
     # the optional pre-reset membrane output (backward residual) rides as a
     # concatenated extra: both lists stay statically resolvable for the
     # pallas-consistency analysis rule
     extra_specs = [seq_spec] if save_u else []
     extra_shape = [
-        jax.ShapeDtypeStruct((T, B, e_h_pad, e_w, Cout), jnp.float32),
+        jax.ShapeDtypeStruct(
+            (num_groups, T, B, n_blocks, cout_blk, m), jnp.float32),
     ] if save_u else []
     out_specs = [seq_spec, mem_spec] + extra_specs
     out_shape = [
-        jax.ShapeDtypeStruct((T, B, e_h_pad, e_w, Cout), spikes.dtype),
-        jax.ShapeDtypeStruct((B, e_h_pad, e_w, Cout), v0.dtype),
+        jax.ShapeDtypeStruct(
+            (num_groups, T, B, n_blocks, cout_blk, m), spikes.dtype),
+        jax.ShapeDtypeStruct(
+            (num_groups, B, n_blocks, cout_blk, m), v0.dtype),
     ] + extra_shape
 
-    kernel = _make_kernel(R, T, block_rows, e_w, float(v_th), save_u=save_u)
+    kernel = _make_kernel(R, block_rows, e_w, n_blocks, float(v_th),
+                          save_u=save_u)
     outs = pl.pallas_call(
         kernel,
-        grid=(B, n_blocks, num_groups),
+        grid=(B, n_blocks, num_groups, T),
         in_specs=[
-            pl.BlockSpec((T, B, n_blocks), lambda b, i, g: (0, 0, 0)),
-            # halo input block per (b, i): element offsets (pl.unblocked)
-            pl.BlockSpec((T, 1, halo_rows, w_pad, Cin),
-                         lambda b, i, g: (0, b, i * block_rows, 0, 0),
-                         indexing_mode=pl.unblocked),
-            pl.BlockSpec((R, R, Cin, cout_blk), lambda b, i, g: (0, 0, 0, g)),
-            pl.BlockSpec((cout_blk,), lambda b, i, g: (g,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),                  # counts
+            # halo input block per (t, b, i): element offsets (pl.Element)
+            pl.BlockSpec((pl.squeezed, pl.squeezed, pl.Element(halo_rows),
+                          pl.Element(w_pad), pl.Element(Cin)),
+                         lambda b, i, g, t: (t, b, i * block_rows, 0, 0)),
+            pl.BlockSpec((pl.squeezed, R, R, cout_blk, Cin),
+                         lambda b, i, g, t: (g, 0, 0, 0, 0)),
+            pl.BlockSpec((pl.squeezed, cout_blk, 1),
+                         lambda b, i, g, t: (g, 0, 0)),
             mem_spec,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((cout_blk, m), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(counts, x, w, bias, vp)
+    )(counts, x, group_taps(w, num_groups),
+      bias.reshape(num_groups, cout_blk, 1),
+      to_block_layout(vp, num_groups, block_rows))
+    outs = [from_block_layout(o, e_w) for o in outs]
     if save_u:
         s_out, v_out, u_out = outs
         return s_out[:, :, :e_h], v_out[:, :e_h], u_out[:, :, :e_h]
@@ -215,7 +237,7 @@ def spiking_conv_lif_pallas(
     aprc: bool = True,
     block_rows: int = 8,
     num_groups: int = 4,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused conv+LIF over a spike train (forward only).
 
@@ -234,7 +256,7 @@ def spiking_conv_lif_pallas(
 def spiking_conv_lif_fwd_pallas(
     spikes: jax.Array, v0: jax.Array, w: jax.Array, bias: jax.Array,
     *, v_th: float = 1.0, aprc: bool = True, block_rows: int = 8,
-    num_groups: int = 4, interpret: bool = True,
+    num_groups: int = 4, interpret: bool,
 ):
     """Forward that additionally emits the **pre-reset membrane** train
     ``u: (T, B, E_h, E_w, Cout) f32`` — the saved residual of the VJP
@@ -272,20 +294,26 @@ def lif_bwd_xla(u: jax.Array, g_s: jax.Array, g_v: jax.Array, *,
     return lam_rev[::-1], dv0
 
 
-def _make_bwd_kernel(t_steps: int, v_th: float, alpha: float, kind: str):
-    def kernel(u_ref, gs_ref, gv_ref, lam_ref, dv0_ref):
-        def step(i, c):
-            t = t_steps - 1 - i
-            u = u_ref[t, 0].astype(jnp.float32)
-            g_s = gs_ref[t, 0].astype(jnp.float32)
-            surr = surrogate_grad(u - v_th, alpha, kind)   # plain jnp
-            lam = c + (g_s - v_th * c) * surr
-            lam_ref[t, 0] = lam.astype(lam_ref.dtype)
-            return lam
+def _make_bwd_kernel(v_th: float, alpha: float, kind: str):
+    def kernel(u_ref, gs_ref, gv_ref, lam_ref, dv0_ref, c_scr):
+        # grid axis 3 walks time backward: cell j holds timestep T-1-j
+        j = pl.program_id(3)
 
-        c = jax.lax.fori_loop(0, t_steps, step,
-                              gv_ref[0].astype(jnp.float32))
-        dv0_ref[...] = c[None].astype(dv0_ref.dtype)
+        @pl.when(j == 0)
+        def _load_gv():
+            c_scr[...] = gv_ref[...].astype(jnp.float32)
+
+        c = c_scr[...]
+        u = u_ref[...].astype(jnp.float32)
+        g_s = gs_ref[...].astype(jnp.float32)
+        surr = surrogate_grad(u - v_th, alpha, kind)       # plain jnp
+        lam = c + (g_s - v_th * c) * surr
+        lam_ref[...] = lam.astype(lam_ref.dtype)
+        c_scr[...] = lam
+
+        @pl.when(j == pl.num_programs(3) - 1)
+        def _store_dv0():
+            dv0_ref[...] = lam.astype(dv0_ref.dtype)
 
     return kernel
 
@@ -300,11 +328,12 @@ def lif_bwd_pallas(
     g_v: jax.Array,          # (B, E_h, E_w, Cout) final-membrane cotangent
     *,
     v_th: float, alpha: float, kind: str,
-    block_rows: int = 8, num_groups: int = 4, interpret: bool = True,
+    block_rows: int = 8, num_groups: int = 4, interpret: bool,
 ):
-    """Pallas reverse-time LIF backward: the T-loop runs backward inside
-    the kernel, the running current-cotangent stays in registers.  Same
-    (B, row-block, channel-group) grid as the forward kernel.
+    """Pallas reverse-time LIF backward: the same (B, row-block,
+    channel-group) grid as the forward kernel plus an innermost sequential
+    T axis walked backward, the running current-cotangent carried across
+    it in VMEM scratch.
 
     Returns ``(lam: (T, B, E_h, E_w, Cout) f32, dv0: (B, E_h, E_w, Cout))``.
     """
@@ -317,27 +346,36 @@ def lif_bwd_pallas(
     def pad_rows(a):
         pads = [(0, 0)] * a.ndim
         pads[-3] = (0, e_h_pad - e_h)
-        return jnp.pad(a, pads)
+        return to_block_layout(jnp.pad(a, pads), num_groups, block_rows)
 
     up, gsp, gvp = pad_rows(u), pad_rows(g_s), pad_rows(g_v)
 
-    seq_spec = pl.BlockSpec((T, 1, block_rows, e_w, cout_blk),
-                            lambda b, i, g: (0, b, i, 0, g))
-    mem_spec = pl.BlockSpec((1, block_rows, e_w, cout_blk),
-                            lambda b, i, g: (b, i, 0, g))
-    kernel = _make_bwd_kernel(T, float(v_th), float(alpha), kind)
+    m = block_rows * e_w
+    seq_spec = pl.BlockSpec(
+        (pl.squeezed, pl.squeezed, pl.squeezed, pl.squeezed, cout_blk, m),
+        lambda b, i, g, j: (g, T - 1 - j, b, i, 0, 0))
+    mem_spec = pl.BlockSpec(
+        (pl.squeezed, pl.squeezed, pl.squeezed, cout_blk, m),
+        lambda b, i, g, j: (g, b, i, 0, 0))
+    kernel = _make_bwd_kernel(float(v_th), float(alpha), kind)
     lam, dv0 = pl.pallas_call(
         kernel,
-        grid=(B, n_blocks, num_groups),
+        grid=(B, n_blocks, num_groups, T),
         in_specs=[seq_spec, seq_spec, mem_spec],
         out_specs=[seq_spec, mem_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((T, B, e_h_pad, e_w, Cout), jnp.float32),
-            jax.ShapeDtypeStruct((B, e_h_pad, e_w, Cout), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (num_groups, T, B, n_blocks, cout_blk, m), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (num_groups, B, n_blocks, cout_blk, m), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((cout_blk, m), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(up, gsp, gvp)
-    return lam[:, :, :e_h], dv0[:, :e_h]
+    return (from_block_layout(lam, e_w)[:, :, :e_h],
+            from_block_layout(dv0, e_w)[:, :e_h])
 
 
 # ---------------------------------------------------------------------------
@@ -346,12 +384,13 @@ def lif_bwd_pallas(
 
 
 class ConvLIFOpts(NamedTuple):
-    """Hashable static config of the trainable fused op (nondiff arg 0)."""
+    """Hashable static config of the trainable fused op (nondiff arg 0).
+    ``interpret`` has no default: ``ops`` resolves it in one place."""
+    interpret: bool
     v_th: float = 1.0
     aprc: bool = True
     block_rows: int = 8
     num_groups: int = 4
-    interpret: bool = True
     surrogate_alpha: float = 10.0
     surrogate_kind: str = "fast_sigmoid"
     bwd: str = "xla"         # "pallas" | "xla" backward implementation

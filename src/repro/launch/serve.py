@@ -222,6 +222,8 @@ def main():
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
     configure_logging(args.log_level)
+    from repro.runtime.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     if args.snn:
         serve_snn(args)

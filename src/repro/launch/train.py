@@ -110,6 +110,8 @@ def main():
                     help="stderr log verbosity (repro.obs.log)")
     args = ap.parse_args()
     configure_logging(args.log_level)
+    from repro.runtime.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     if args.snn:
         train_snn(args)

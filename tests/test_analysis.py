@@ -365,6 +365,49 @@ def run(x, save, n_blocks, block_rows, W):
     assert _check(tmp_path, "kernels/k.py", src) == []
 
 
+PALLAS_MOSAIC_SPECS = """\
+import jax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+def kernel(c_ref, x_ref, o_ref):
+    o_ref[...] = x_ref[...]
+
+def run(counts, x, n_blocks, block_rows, W, G, cb):
+    halo = block_rows + 2
+    return pl.pallas_call(
+        kernel,
+        grid=(4, n_blocks, G),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((pl.squeezed, pl.Element(halo), pl.Element(W)),
+                         lambda b, i, g: (b, i * block_rows, 0)),
+        ],
+        out_specs=pl.BlockSpec((pl.squeezed, None, cb, 7),
+                               lambda b, i, g: (g, b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((G, 4, cb, 7), x.dtype),
+    )(counts, x)
+"""
+
+
+@pytest.mark.parametrize("mixed", [False, True])
+def test_pallas_rule_element_squeezed_and_smem_specs(tmp_path, mixed):
+    # the Mosaic-era spec idioms: a whole-array SMEM skip table, an
+    # all-Element halo window next to a squeezed batch dim, squeezed output
+    # dims (size 1, never a divisibility finding) — clean as written; a
+    # halo window mixing Element and plain dims is the one finding
+    src = PALLAS_MOSAIC_SPECS
+    if mixed:
+        src = src.replace("pl.Element(W)", "W")
+    fs = _check(tmp_path, "kernels/k.py", src)
+    if not mixed:
+        assert fs == []
+    else:
+        assert _rules_hit(fs) == {"pallas-consistency"}
+        assert len(fs) == 1 and "in_specs[1]" in fs[0].message
+        assert "mixes pl.Element" in fs[0].message
+
+
 # -- api-hygiene -------------------------------------------------------------
 
 def test_print_ban_inside_repro_package(tmp_path):

@@ -9,7 +9,7 @@ Acceptance contract covered here (ISSUE: multi-device execution):
   * logits are bit-exact sharded-vs-single-device (1 vs 2 vs 4 devices,
     and mesh-vs-no-mesh);
   * train_step params are bit-exact across device counts on both the SPMD
-    path (batched backend) and the shard_map fallback (ref backend);
+    path (batched backend) and the shard_map path (ref and pallas backends);
   * CBWS device placement balances skewed loads at least as well as the
     FIFO striping baseline;
   * the sharded threaded engine conserves requests through a lane death
@@ -228,6 +228,22 @@ for n in (1, 4):
     rp[n] = s.params
 out["train_parity_ref_4v1"] = eq_tree(rp[4], rp[1])
 
+# pallas backend: shard_map (a Mosaic kernel cannot be SPMD-partitioned)
+pl_logits, pl_counts, pl_params = {}, {}, {}
+for n in (1, 4):
+    s = api.Session(cfg, api.TrainSpec(backend="pallas", mesh={"data": n}),
+                    seed=0)
+    o = s.infer(frames)
+    pl_logits[n] = np.asarray(o.logits)
+    pl_counts[n] = [np.asarray(c) for c in o.timestep_counts]
+    s.train_step(frames, labels)
+    pl_params[n] = s.params
+out["pallas_logits_parity_4v1"] = bool(
+    np.array_equal(pl_logits[4], pl_logits[1]))
+out["pallas_counts_parity_4v1"] = all(
+    np.array_equal(a, b) for a, b in zip(pl_counts[4], pl_counts[1]))
+out["pallas_train_parity_4v1"] = eq_tree(pl_params[4], pl_params[1])
+
 # sharded threaded engine: lane death conservation + device pinning
 sess = api.Session(cfg, seed=0)
 spec = api.ServeSpec(mesh={"data": 2}, num_lanes=4, threaded=True,
@@ -292,6 +308,13 @@ def test_train_params_bit_parity_across_device_counts(dist_results):
 @pytest.mark.slow
 def test_train_params_bit_parity_ref_backend(dist_results):
     assert dist_results["train_parity_ref_4v1"]
+
+
+@pytest.mark.slow
+def test_pallas_mesh_bit_parity_across_device_counts(dist_results):
+    assert dist_results["pallas_logits_parity_4v1"]
+    assert dist_results["pallas_counts_parity_4v1"]
+    assert dist_results["pallas_train_parity_4v1"]
 
 
 @pytest.mark.slow
