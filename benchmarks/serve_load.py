@@ -32,11 +32,6 @@ Three sections, each a ``name,us_per_call,derived`` row family:
                        mid-flight SLO degrade vs whole-T dispatch, plus a
                        no-SLO burst asserting bit-identical logits between
                        the two engines (the chunk-parity contract)
-  serve/obs/*          observability tax: the same burst drained with
-                       lifecycle tracing off vs on (ServeSpec.trace) — the
-                       traced/untraced wall ratio must stay under 1.05x;
-                       quick mode also exports the traced run as Chrome
-                       trace-event JSON (BENCH_trace.json, Perfetto-loadable)
 
 Engines are constructed exclusively through the ``repro.api`` facade
 (``ServeSpec`` -> ``Session``); ``--quick`` shrinks the workload and writes
@@ -58,7 +53,6 @@ import jax
 import numpy as np
 
 BENCH_JSON = "BENCH_serving.json"
-TRACE_JSON = "BENCH_trace.json"
 
 # Lane-level (inter-op) parallelism is what the serve/threaded/* section
 # measures: it runs in a SUBPROCESS with XLA CPU pinned to one intra-op
@@ -423,68 +417,6 @@ def faults_rows(params, cfg, quick: bool):
     ]
 
 
-def obs_rows(params, cfg, quick: bool):
-    """(g) observability tax: the same heavy-first skewed burst drained by
-    the single-thread engine with lifecycle tracing off vs on
-    (``ServeSpec.trace``) — a disabled recorder is one attribute check per
-    emit site, an enabled one an append under a lock, and the acceptance
-    bar is traced/untraced wall < 1.05x.  Interleaved pairs +
-    median-of-ratios (the bench_kernels timing discipline).  Quick mode
-    additionally exports the last traced run as Chrome trace-event JSON
-    (``BENCH_trace.json``) so every PR leaves a Perfetto-loadable artifact
-    alongside the numeric rows."""
-    from repro import api
-
-    lanes, max_batch = 2, 8
-    n, pairs = (32, 5) if quick else (96, 9)
-    frames = _skewed_frames(n, cfg, seed=19)
-    order = np.argsort(-frames.sum(axis=(1, 2, 3)))
-    sess = api.Session(cfg, params=params)
-
-    def build(trace):
-        eng = sess.engine(api.ServeSpec(
-            backend="batched", num_lanes=lanes, max_batch=max_batch,
-            buckets=(max_batch,), keep_logits=False, trace=trace))
-        for i in order:
-            eng.submit(frames[i], arrival=0.0)
-        return eng
-
-    def timed(eng):
-        eng.warmup()                          # compiles outside the wall
-        t0 = time.perf_counter()
-        eng.run()
-        return time.perf_counter() - t0, eng
-
-    build(True).run()                         # burn in
-    walls = {False: [], True: []}
-    ratios, traced = [], None
-    for _ in range(pairs):
-        w0, _ = timed(build(False))
-        w1, traced = timed(build(True))
-        walls[False].append(w0)
-        walls[True].append(w1)
-        ratios.append(w1 / w0)
-    us0 = statistics.median(walls[False]) * 1e6
-    us1 = statistics.median(walls[True]) * 1e6
-    ratio = statistics.median(ratios)
-    events = len(traced.trace)
-    if quick:
-        from repro.obs.export import write_chrome_trace
-        write_chrome_trace(traced.trace, TRACE_JSON)
-    return [
-        {"name": "serve/obs/untraced",
-         "us_per_call": us0,
-         "derived": f"wall_fps={n / (us0 / 1e6):.1f};lanes={lanes};n={n}"},
-        {"name": "serve/obs/trace_overhead",
-         "us_per_call": us1,
-         "derived": (f"wall_fps={n / (us1 / 1e6):.1f};lanes={lanes};n={n};"
-                     f"events={events};dropped={traced.trace.dropped};"
-                     f"overhead={ratio:.4f}x;"
-                     f"overhead_pct={(ratio - 1.0) * 100:.2f};"
-                     f"under_5pct={ratio < 1.05}")},
-    ]
-
-
 def chunked_rows(params, cfg, quick: bool):
     """(h) timestep-chunked continuous batching (ExecutionSpec
     .chunk_timesteps) under a bursty 3x-overload Poisson trace — the
@@ -627,7 +559,6 @@ def run(quick: bool = True, section: str = "all"):
     rows += load_rows(params, cfg, quick)
     rows += throughput_rows(params, cfg, quick)
     rows += chunked_rows(params, cfg, quick)
-    rows += obs_rows(params, cfg, quick)
     if jax.default_backend() == "cpu":
         rows += threaded_rows_subprocess(quick)
     else:

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.api.specs import ExecutionSpec, ServeSpec, TrainSpec
 from repro.config import SNNConfig, get_snn
+from repro.obs import spans
 
 __all__ = ["Session", "LiveServer"]
 
@@ -66,6 +67,8 @@ class Session:
         self._eval_fn = None
         self._device_mesh = None                 # repro.dist.DeviceMesh
         self._mesh_runner = None                 # repro.dist.MeshRunner
+        self._calls = 0                          # infer calls, for spans
+        self._steps = 0                          # train steps, for spans
 
     # -- mesh plumbing -------------------------------------------------------
     def _device_mesh_for(self, mesh_axes):
@@ -155,17 +158,24 @@ class Session:
 
         With a mesh in the spec, the batch axis is sharded over the data
         axis by the session's ``MeshRunner`` — per-row logits stay
-        bit-identical to single-device execution (docs/dist.md)."""
-        frames = np.asarray(frames, dtype=np.float32)
-        n = frames.shape[0]
-        if bucket is not None and bucket < n:
-            raise ValueError(f"bucket={bucket} cannot hold a batch of {n}")
-        runner = self._runner()
-        if runner is not None:
-            return runner.infer(self.params, frames, pad_to=bucket)
-        eng = self._single_shot_engine(n if bucket is None
-                                       else max(n, int(bucket)))
-        return eng.infer(frames, bucket=bucket)
+        bit-identical to single-device execution (docs/dist.md).
+
+        The call is one ``snn.infer`` span in a running profiler trace; the
+        engine opens its phases (``obs.spans``)."""
+        self._calls += 1
+        with spans.span(spans.INFER, call=self._calls - 1,
+                        batch=len(frames)):
+            frames = np.asarray(frames, dtype=np.float32)
+            n = frames.shape[0]
+            if bucket is not None and bucket < n:
+                raise ValueError(
+                    f"bucket={bucket} cannot hold a batch of {n}")
+            runner = self._runner()
+            if runner is not None:
+                return runner.infer(self.params, frames, pad_to=bucket)
+            eng = self._single_shot_engine(n if bucket is None
+                                           else max(n, int(bucket)))
+            return eng.infer(frames, bucket=bucket)
 
     def serve(self, frames: np.ndarray, *, steps: int = 1) -> Dict[str, float]:
         """Single-shot serving: ``steps`` iterations of one fixed batch
@@ -230,27 +240,39 @@ class Session:
         With a mesh, the batch shards over the data axis and the step runs
         through the session's ``MeshRunner`` — per-example gradient rows
         combined canonically on the host, so the updated params are
-        bit-identical to single-device training on the same inputs."""
-        if self._mom is None:
-            self._mom = jax.tree.map(jnp.zeros_like, self.params)
-        runner = self._runner()
-        if runner is not None:
-            self.params, self._mom, loss = runner.train_step(
-                self.params, self._mom, x, y)
-        else:
-            if self._train_step is None:
-                from repro.core.snn_train import make_train_step
-                self._train_step = jax.jit(
-                    make_train_step(self.cfg, spec=self._as_train_spec()))
-            self.params, self._mom, loss = self._train_step(
-                self.params, self._mom, jnp.asarray(x), jnp.asarray(y))
-        # compiled executables are params-independent (params are a traced
-        # argument): swap the new params into the cached engines in place
-        # instead of dropping them, so train/infer interleaves never
-        # recompile
-        for eng in self._engines.values():
-            eng.update_params(self.params)
-        return float(loss)
+        bit-identical to single-device training on the same inputs.
+
+        The step is one ``snn.train_step`` span in a running profiler
+        trace, with its phases as children (``obs.spans``)."""
+        self._steps += 1
+        with spans.span(spans.TRAIN_STEP, step=self._steps - 1,
+                        batch=len(x)):
+            if self._mom is None:
+                self._mom = jax.tree.map(jnp.zeros_like, self.params)
+            runner = self._runner()
+            if runner is not None:
+                self.params, self._mom, loss = runner.train_step(
+                    self.params, self._mom, x, y)
+            else:
+                if self._train_step is None:
+                    from repro.core.snn_train import make_train_step
+                    self._train_step = jax.jit(make_train_step(
+                        self.cfg, spec=self._as_train_spec()))
+                with spans.span(spans.STAGE, batch=len(x)):
+                    x, y = jnp.asarray(x), jnp.asarray(y)
+                with spans.span(spans.DISPATCH, batch=len(x)):
+                    self.params, self._mom, loss = self._train_step(
+                        self.params, self._mom, x, y)
+            # compiled executables are params-independent (params are a
+            # traced argument): swap the new params into the cached engines
+            # in place instead of dropping them, so train/infer interleaves
+            # never recompile
+            with spans.span(spans.UPDATE_ENGINES,
+                            engines=len(self._engines)):
+                for eng in self._engines.values():
+                    eng.update_params(self.params)
+            with spans.span(spans.SYNC, batch=len(x)):
+                return float(loss)
 
     def evaluate(self, x, y) -> float:
         """Classification accuracy through the spec-selected backend (the

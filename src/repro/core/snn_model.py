@@ -53,7 +53,7 @@ from repro.core.surrogate import spike_fn
 __all__ = ["init_snn", "snn_apply", "SNNOutputs", "layer_shapes",
            "SNN_BACKENDS", "ChunkCarry", "ChunkOutputs", "init_chunk_carry",
            "chunk_lengths", "snn_apply_chunk", "snn_apply_chunked",
-           "finalize_logits"]
+           "finalize_logits", "skip_cells"]
 
 SNN_BACKENDS = ("ref", "batched", "pallas")
 
@@ -159,6 +159,26 @@ def layer_shapes(cfg: SNNConfig) -> List[Tuple[int, int, int]]:
         h, w = L.conv_out_hw(h, w, cfg.kernel_size, cfg.aprc)
         shapes.append((h, w, cout))
     return shapes
+
+
+def skip_cells(cfg: SNNConfig, batch: int, timesteps: int, *,
+               hoist: bool = True, block_rows: int = 8) -> Tuple[int, ...]:
+    """Cells of the skip table, (timestep, batch row, row block), of each
+    layer that ``SNNOutputs.skip_fractions`` covers on the pallas backend,
+    in its order: every conv layer but a hoisted first one (``hoist``:
+    direct-coded frames, not a spike train).  A fraction times its count is
+    the number of cells the kernel skipped."""
+    r = cfg.kernel_size
+    n_conv = len(cfg.conv_channels)
+    h_in = [cfg.input_hw[0]] + [h for h, _, _ in layer_shapes(cfg)[:-1]]
+    cells = []
+    for i in range(n_conv):
+        readout = i == n_conv - 1 and not cfg.dense_units
+        if hoist and i == 0 and not readout:
+            continue
+        e_h = h_in[i] + r - 1 if cfg.aprc else h_in[i]
+        cells.append(timesteps * batch * -(-e_h // block_rows))
+    return tuple(cells)
 
 
 def init_snn(key: jax.Array, cfg: SNNConfig) -> Dict:
@@ -409,18 +429,20 @@ def _conv_xla(x: jax.Array, p: Dict, aprc: bool) -> jax.Array:
 
 
 def _conv_folded(x_seq: jax.Array, p: Dict, cfg: SNNConfig,
-                 use_pallas: bool, num_groups: int) -> jax.Array:
+                 use_pallas: bool, num_groups: int,
+                 layer: Optional[int] = None) -> jax.Array:
     """Time-batched synaptic current: fold (T, B) -> T*B and convolve once.
 
     The fold puts the full spatio-temporal workload on the kernel's batch
     grid axis, so its spike-count skip table covers (T x B x row-blocks).
+    ``layer`` names the Pallas kernel (``conv.l<layer>``).
     """
     t, b = x_seq.shape[:2]
     x = x_seq.reshape((t * b,) + x_seq.shape[2:])
     if use_pallas:
         from repro.kernels import ops
         z = ops.spiking_conv(x, p["w"], p["b"], aprc=cfg.aprc,
-                             num_groups=num_groups)
+                             num_groups=num_groups, layer=layer)
     else:
         z = _conv_xla(x, p, cfg.aprc)
     return z.reshape((t, b) + z.shape[1:])
@@ -525,7 +547,7 @@ def _time_batched_chunk(params: Dict, frames: jax.Array, cfg: SNNConfig,
                 x = jnp.broadcast_to(x[None], (T,) + x.shape)
                 hoist = False
             note_skip(x, p["w"].shape[0])
-            z = _conv_folded(x, p, cfg, use_pallas, groups)
+            z = _conv_folded(x, p, cfg, use_pallas, groups, layer=i)
 
             def seg_body(v, z_t):
                 v = v + z_t
@@ -538,7 +560,7 @@ def _time_batched_chunk(params: Dict, frames: jax.Array, cfg: SNNConfig,
             if use_pallas:
                 from repro.kernels import ops
                 z1 = ops.spiking_conv(x, p["w"], p["b"], aprc=cfg.aprc,
-                                      num_groups=groups)
+                                      num_groups=groups, layer=i)
             else:
                 z1 = _conv_xla(x, p, cfg.aprc)
             s, cnt, v_fin = _lif_scan_const(z1, T, v_th, surrogate_alpha,
@@ -554,10 +576,10 @@ def _time_batched_chunk(params: Dict, frames: jax.Array, cfg: SNNConfig,
                     x, carry.conv_v[i], p["w"], p["b"], v_th=float(v_th),
                     aprc=cfg.aprc, num_groups=groups,
                     surrogate_alpha=surrogate_alpha,
-                    surrogate_kind=surrogate_kind)
+                    surrogate_kind=surrogate_kind, layer=i)
                 cnt = s.sum(axis=(1, 2, 3))
             else:
-                z = _conv_folded(x, p, cfg, use_pallas, groups)
+                z = _conv_folded(x, p, cfg, use_pallas, groups, layer=i)
                 s, cnt, v_fin = _lif_scan(z, v_th, surrogate_alpha,
                                           surrogate_kind, v0=carry.conv_v[i])
             new_conv_v.append(v_fin)

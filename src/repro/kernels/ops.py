@@ -28,7 +28,7 @@ from repro.kernels import ref
 from repro.kernels.lif import lif_fused_pallas
 from repro.kernels.spiking_conv import (conv_grad_input_pallas,
                                         conv_grad_input_xla,
-                                        conv_grad_weights_xla,
+                                        conv_grad_weights_xla, kernel_name,
                                         skip_table_fraction,
                                         spiking_conv_pallas)
 from repro.kernels.spiking_conv_lif import (ConvLIFOpts, _largest_divisor,
@@ -69,13 +69,15 @@ class _ConvOpts(NamedTuple):
     block_rows: int = 8
     num_groups: int = 4
     bwd: str = "xla"
+    layer: Optional[int] = None   # network layer, for the kernels' names
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _spiking_conv_vjp(opts: _ConvOpts, spikes, w, bias):
     return spiking_conv_pallas(
         spikes, w, bias, aprc=opts.aprc, block_rows=opts.block_rows,
-        num_groups=opts.num_groups, interpret=opts.interpret)
+        num_groups=opts.num_groups, interpret=opts.interpret,
+        name=kernel_name("conv", opts.layer))
 
 
 def _spiking_conv_fwd(opts, spikes, w, bias):
@@ -88,7 +90,8 @@ def _spiking_conv_bwd(opts, res, g):
         groups = _largest_divisor(w.shape[2], opts.num_groups)
         dx = conv_grad_input_pallas(
             g, w, aprc=opts.aprc, block_rows=opts.block_rows,
-            num_groups=groups, interpret=opts.interpret)
+            num_groups=groups, interpret=opts.interpret,
+            name=kernel_name("conv_grad_input", opts.layer))
     else:
         dx = conv_grad_input_xla(g, w, aprc=opts.aprc)
     dw, db = conv_grad_weights_xla(spikes, g, aprc=opts.aprc, r=w.shape[0])
@@ -102,16 +105,19 @@ def spiking_conv(
     spikes: jax.Array, w: jax.Array, bias: jax.Array,
     *, aprc: bool = True, block_rows: int = 8, num_groups: int = 4,
     interpret: Optional[bool] = None, bwd: Optional[str] = None,
+    layer: Optional[int] = None,
 ) -> jax.Array:
     """Spike-driven conv (see kernels.spiking_conv).  Output matches
     ``ref.spiking_conv_ref`` exactly up to float accumulation order.
-    Differentiable (transposed-tap backward)."""
+    Differentiable (transposed-tap backward).  ``layer``, the network
+    layer's index, names the kernels ``conv.l<layer>`` and
+    ``conv_grad_input.l<layer>`` (``spiking_conv.kernel_name``)."""
     if interpret is None:
         interpret = default_interpret()
     if bwd is None:
         bwd = _default_bwd(interpret)
     opts = _ConvOpts(aprc=aprc, block_rows=block_rows, num_groups=num_groups,
-                     interpret=interpret, bwd=bwd)
+                     interpret=interpret, bwd=bwd, layer=layer)
     return _spiking_conv_vjp(opts, spikes, w, bias)
 
 
@@ -146,6 +152,7 @@ def spiking_conv_lif(
     num_groups: int = 4, interpret: Optional[bool] = None,
     surrogate_alpha: float = 10.0, surrogate_kind: str = "fast_sigmoid",
     bwd: Optional[str] = None, spec: Optional[object] = None,
+    layer: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused conv+LIF over a whole spike train (see kernels.spiking_conv_lif).
 
@@ -156,7 +163,9 @@ def spiking_conv_lif(
     Differentiable: ``jax.grad`` applies the selectable surrogate
     (``surrogate_kind`` in core.surrogate.SURROGATE_KINDS, scaled by
     ``surrogate_alpha``) through reverse-time BPTT — the same gradient the
-    ``backend="ref"`` scan computes.
+    ``backend="ref"`` scan computes.  ``layer``, the network layer's index,
+    names its kernels ``conv_lif_fwd.l<layer>``, ``lif_bwd.l<layer>`` and
+    ``conv_grad_input.l<layer>`` (``spiking_conv.kernel_name``).
 
     ``spec`` (a ``repro.api.ExecutionSpec``, duck-typed) overrides the
     surrogate kwargs — the facade threads one validated record all the way
@@ -202,7 +211,7 @@ def spiking_conv_lif(
         v_th=float(v_th), aprc=aprc, block_rows=block_rows,
         num_groups=num_groups, interpret=interpret,
         surrogate_alpha=float(surrogate_alpha),
-        surrogate_kind=surrogate_kind, bwd=bwd)
+        surrogate_kind=surrogate_kind, bwd=bwd, layer=layer)
     return spiking_conv_lif_train(opts, spikes, v0, w, bias)
 
 
@@ -212,6 +221,7 @@ def spiking_conv_lif_chunked(
     block_rows: int = 8, num_groups: int = 4,
     interpret: Optional[bool] = None, surrogate_alpha: float = 10.0,
     surrogate_kind: str = "fast_sigmoid", bwd: Optional[str] = None,
+    layer: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Chunked driver for the fused conv+LIF kernel: run the T-loop in
     segments of ``chunk_timesteps``, threading the membrane between
@@ -233,7 +243,7 @@ def spiking_conv_lif_chunked(
             spikes[t0:t0 + c], v, w, bias, v_th=v_th, aprc=aprc,
             block_rows=block_rows, num_groups=num_groups,
             interpret=interpret, surrogate_alpha=surrogate_alpha,
-            surrogate_kind=surrogate_kind, bwd=bwd)
+            surrogate_kind=surrogate_kind, bwd=bwd, layer=layer)
         s_parts.append(s)
         t0 += c
     return jnp.concatenate(s_parts, axis=0), v

@@ -67,7 +67,17 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["spiking_conv_kernel", "spiking_conv_pallas", "row_block_counts",
            "skip_table_fraction", "conv_grad_input_xla",
            "conv_grad_input_pallas", "conv_grad_weights_xla", "conv_pads",
-           "to_block_layout", "from_block_layout", "group_taps", "tap_gemm"]
+           "to_block_layout", "from_block_layout", "group_taps", "tap_gemm",
+           "kernel_name"]
+
+
+def kernel_name(kind: str, layer: int | None) -> str | None:
+    """The stable name of one network layer's kernel, ``<kind>.l<layer>``
+    (``conv``, ``conv_lif_fwd``, ``lif_bwd``, ``conv_grad_input``), or None
+    without a layer.  It is the ``pallas_call``'s name, and so the name of
+    its HLO instruction and of its op in a profiler trace, whatever
+    ``jvp`` or ``transpose`` wraps the call."""
+    return None if layer is None else f"{kind}.l{layer}"
 
 
 def conv_pads(r: int, aprc: bool) -> tuple:
@@ -206,7 +216,7 @@ def skip_table_fraction(spikes: jax.Array, r: int, *, aprc: bool = True,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("aprc", "block_rows", "num_groups", "interpret"))
+    static_argnames=("aprc", "block_rows", "num_groups", "interpret", "name"))
 def spiking_conv_pallas(
     spikes: jax.Array,       # (B, H, W, Cin) binary; B may fold T x batch
     w: jax.Array,            # (R, R, Cin, Cout) — CBWS-permuted
@@ -216,8 +226,10 @@ def spiking_conv_pallas(
     block_rows: int = 8,
     num_groups: int = 4,
     interpret: bool,
+    name: str | None = None,
 ) -> jax.Array:
-    """Returns dV: (B, E_h, E_w, Cout); E = H+R-1 (APRC) or H (same-pad)."""
+    """Returns dV: (B, E_h, E_w, Cout); E = H+R-1 (APRC) or H (same-pad).
+    ``name`` names the kernel (``kernel_name``)."""
     B, H, W, Cin = spikes.shape
     R, _, _, Cout = w.shape
     assert Cout % num_groups == 0, (Cout, num_groups)
@@ -245,6 +257,7 @@ def spiking_conv_pallas(
     kernel = _make_kernel(R, block_rows, e_w, n_blocks)
     out = pl.pallas_call(
         kernel,
+        name=name,
         grid=(B, n_blocks, num_groups),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                  # counts
@@ -321,7 +334,8 @@ def _make_grad_input_kernel(r: int, block_rows: int, w_out: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("aprc", "block_rows", "num_groups", "interpret"))
+    jax.jit, static_argnames=("aprc", "block_rows", "num_groups", "interpret",
+                              "name"))
 def conv_grad_input_pallas(
     dz: jax.Array,           # (N, E_h, E_w, Cout) conv-output cotangent
     w: jax.Array,            # (R, R, Cin, Cout) forward taps
@@ -330,6 +344,7 @@ def conv_grad_input_pallas(
     block_rows: int = 8,
     num_groups: int = 1,     # lanes over Cin (the *output* channels here)
     interpret: bool,
+    name: str | None = None,
 ) -> jax.Array:
     """Pallas transposed-tap backward kernel: dL/d(input), (N, H, W, Cin)."""
     N, e_h, e_w, Cout = dz.shape
@@ -353,6 +368,7 @@ def conv_grad_input_pallas(
     kernel = _make_grad_input_kernel(R, block_rows, W)
     out = pl.pallas_call(
         kernel,
+        name=name,
         grid=(N, n_blocks, num_groups),
         in_specs=[
             pl.BlockSpec((pl.squeezed, pl.Element(halo_rows),

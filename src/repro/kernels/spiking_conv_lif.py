@@ -70,7 +70,7 @@ in that resolvable shape.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,8 +82,8 @@ from repro.kernels.spiking_conv import (conv_grad_input_pallas,
                                         conv_grad_input_xla,
                                         conv_grad_weights_xla,
                                         from_block_layout, group_taps,
-                                        row_block_counts, tap_gemm,
-                                        to_block_layout)
+                                        kernel_name, row_block_counts,
+                                        tap_gemm, to_block_layout)
 
 __all__ = ["spiking_conv_lif_pallas", "spiking_conv_lif_fwd_pallas",
            "spiking_conv_lif_train", "ConvLIFOpts", "lif_bwd_pallas",
@@ -131,7 +131,7 @@ def _make_kernel(r: int, block_rows: int, w_out: int, n_blocks: int,
 
 
 def _fused_call(spikes, v0, w, bias, *, v_th, aprc, block_rows, num_groups,
-                interpret, save_u):
+                interpret, save_u, name):
     T, B, H, W, Cin = spikes.shape
     R, _, _, Cout = w.shape
     assert Cout % num_groups == 0, (Cout, num_groups)
@@ -194,6 +194,7 @@ def _fused_call(spikes, v0, w, bias, *, v_th, aprc, block_rows, num_groups,
                           save_u=save_u)
     outs = pl.pallas_call(
         kernel,
+        name=name,
         grid=(B, n_blocks, num_groups, T),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                  # counts
@@ -226,7 +227,8 @@ def _fused_call(spikes, v0, w, bias, *, v_th, aprc, block_rows, num_groups,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("v_th", "aprc", "block_rows", "num_groups", "interpret"))
+    static_argnames=("v_th", "aprc", "block_rows", "num_groups", "interpret",
+                     "name"))
 def spiking_conv_lif_pallas(
     spikes: jax.Array,       # (T, B, H, W, Cin) binary input train
     v0: jax.Array,           # (B, E_h, E_w, Cout) initial membrane
@@ -238,25 +240,28 @@ def spiking_conv_lif_pallas(
     block_rows: int = 8,
     num_groups: int = 4,
     interpret: bool,
+    name: str | None = None,
 ):
     """Fused conv+LIF over a spike train (forward only).
 
     Returns ``(s, v_final)`` with ``s: (T, B, E_h, E_w, Cout)`` the output
     spike train and ``v_final: (B, E_h, E_w, Cout)`` the membrane after the
-    last step; ``E = H+R-1`` (APRC) or ``H`` (same-pad).
+    last step; ``E = H+R-1`` (APRC) or ``H`` (same-pad).  ``name`` names
+    the kernel (``spiking_conv.kernel_name``).
     """
     return _fused_call(spikes, v0, w, bias, v_th=v_th, aprc=aprc,
                        block_rows=block_rows, num_groups=num_groups,
-                       interpret=interpret, save_u=False)
+                       interpret=interpret, save_u=False, name=name)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("v_th", "aprc", "block_rows", "num_groups", "interpret"))
+    static_argnames=("v_th", "aprc", "block_rows", "num_groups", "interpret",
+                     "name"))
 def spiking_conv_lif_fwd_pallas(
     spikes: jax.Array, v0: jax.Array, w: jax.Array, bias: jax.Array,
     *, v_th: float = 1.0, aprc: bool = True, block_rows: int = 8,
-    num_groups: int = 4, interpret: bool,
+    num_groups: int = 4, interpret: bool, name: str | None = None,
 ):
     """Forward that additionally emits the **pre-reset membrane** train
     ``u: (T, B, E_h, E_w, Cout) f32`` — the saved residual of the VJP
@@ -266,7 +271,7 @@ def spiking_conv_lif_fwd_pallas(
     """
     return _fused_call(spikes, v0, w, bias, v_th=v_th, aprc=aprc,
                        block_rows=block_rows, num_groups=num_groups,
-                       interpret=interpret, save_u=True)
+                       interpret=interpret, save_u=True, name=name)
 
 
 # ---------------------------------------------------------------------------
@@ -321,7 +326,7 @@ def _make_bwd_kernel(v_th: float, alpha: float, kind: str):
 @functools.partial(
     jax.jit,
     static_argnames=("v_th", "alpha", "kind", "block_rows", "num_groups",
-                     "interpret"))
+                     "interpret", "name"))
 def lif_bwd_pallas(
     u: jax.Array,            # (T, B, E_h, E_w, Cout) pre-reset membrane
     g_s: jax.Array,          # (T, B, E_h, E_w, Cout) spike cotangent
@@ -329,6 +334,7 @@ def lif_bwd_pallas(
     *,
     v_th: float, alpha: float, kind: str,
     block_rows: int = 8, num_groups: int = 4, interpret: bool,
+    name: str | None = None,
 ):
     """Pallas reverse-time LIF backward: the same (B, row-block,
     channel-group) grid as the forward kernel plus an innermost sequential
@@ -360,6 +366,7 @@ def lif_bwd_pallas(
     kernel = _make_bwd_kernel(float(v_th), float(alpha), kind)
     lam, dv0 = pl.pallas_call(
         kernel,
+        name=name,
         grid=(B, n_blocks, num_groups, T),
         in_specs=[seq_spec, seq_spec, mem_spec],
         out_specs=[seq_spec, mem_spec],
@@ -394,6 +401,7 @@ class ConvLIFOpts(NamedTuple):
     surrogate_alpha: float = 10.0
     surrogate_kind: str = "fast_sigmoid"
     bwd: str = "xla"         # "pallas" | "xla" backward implementation
+    layer: Optional[int] = None   # network layer, for the kernels' names
 
 
 def _largest_divisor(n: int, cap: int) -> int:
@@ -412,14 +420,16 @@ def spiking_conv_lif_train(opts: ConvLIFOpts, spikes, v0, w, bias):
     return spiking_conv_lif_pallas(
         spikes, v0, w, bias, v_th=opts.v_th, aprc=opts.aprc,
         block_rows=opts.block_rows, num_groups=opts.num_groups,
-        interpret=opts.interpret)
+        interpret=opts.interpret,
+        name=kernel_name("conv_lif_fwd", opts.layer))
 
 
 def _train_fwd(opts, spikes, v0, w, bias):
     s, v_final, u = spiking_conv_lif_fwd_pallas(
         spikes, v0, w, bias, v_th=opts.v_th, aprc=opts.aprc,
         block_rows=opts.block_rows, num_groups=opts.num_groups,
-        interpret=opts.interpret)
+        interpret=opts.interpret,
+        name=kernel_name("conv_lif_fwd", opts.layer))
     return (s, v_final), (spikes, w, bias, u)
 
 
@@ -433,7 +443,8 @@ def _train_bwd(opts, res, cts):
         lam, dv0 = lif_bwd_pallas(
             u, g_s, g_v, v_th=opts.v_th, alpha=opts.surrogate_alpha,
             kind=opts.surrogate_kind, block_rows=opts.block_rows,
-            num_groups=opts.num_groups, interpret=opts.interpret)
+            num_groups=opts.num_groups, interpret=opts.interpret,
+            name=kernel_name("lif_bwd", opts.layer))
     else:
         lam, dv0 = lif_bwd_xla(
             u, g_s.astype(jnp.float32), g_v.astype(jnp.float32),
@@ -447,7 +458,8 @@ def _train_bwd(opts, res, cts):
         cin_groups = _largest_divisor(w.shape[2], opts.num_groups)
         dx = conv_grad_input_pallas(
             lam2, w, aprc=opts.aprc, block_rows=opts.block_rows,
-            num_groups=cin_groups, interpret=opts.interpret)
+            num_groups=cin_groups, interpret=opts.interpret,
+            name=kernel_name("conv_grad_input", opts.layer))
     else:
         dx = conv_grad_input_xla(lam2, w, aprc=opts.aprc)
     dw, db = conv_grad_weights_xla(x2, lam2, aprc=opts.aprc, r=R)

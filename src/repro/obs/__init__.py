@@ -23,16 +23,30 @@ The serving engine's observability layer, three seams:
     A structured stderr logger with per-subsystem levels for the launchers
     and examples (quiet by default so tests stay silent).
 
+``obs.spans``
+    ``span(name, **attrs)`` — the program's host spans (``snn.*``) in the
+    ``jax.profiler`` trace, on the device ops' clock: one root per
+    ``Session.infer`` / ``train_step`` call, its phases (stage, dispatch,
+    sync) as children.
+
+``obs.skip``
+    The skip-table counter: skipped and total (t, b, row-block) cells per
+    fused layer, folded without a host sync; the process-level counter
+    counts while a profiler trace records.
+
 See docs/observability.md.
 """
 from repro.obs.log import configure_logging, get_logger
 from repro.obs.snapshot import MetricsSnapshot
 from repro.obs.trace import TERMINAL_KINDS, TraceEvent, TraceRecorder
 from repro.obs.export import chrome_trace, render_timeline, write_chrome_trace
+from repro.obs import skip, spans
+from repro.obs.spans import span
 
 __all__ = [
     "TraceRecorder", "TraceEvent", "TERMINAL_KINDS",
     "chrome_trace", "write_chrome_trace", "render_timeline",
     "MetricsSnapshot",
     "get_logger", "configure_logging",
+    "skip", "spans", "span",
 ]

@@ -4,9 +4,9 @@
 lock.  The engine holds exactly one recorder and calls ``emit`` at every
 lifecycle point unconditionally — a disabled recorder (``EngineConfig.trace``
 off, the default) returns after a single attribute check, which keeps the
-call sites branch-free and the disabled overhead unmeasurable (the
-``serve/obs/trace_overhead`` BENCH row keeps the *enabled* overhead under
-5% too).
+call sites branch-free and the disabled overhead unmeasurable.  These
+events live on the engine's clock; the program's spans on the device
+trace's clock are ``obs.spans``.
 
 Timestamps come from the engine's ``Clock`` (``bind_clock``): under a
 ``VirtualClock`` the single-threaded scheduler emits a deterministic

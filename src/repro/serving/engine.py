@@ -84,6 +84,9 @@ import numpy as np
 
 from repro.config import SNNConfig
 from repro.core.balance import balance_ratio
+from repro.core.snn_model import skip_cells
+from repro.obs import skip as obs_skip
+from repro.obs import spans
 from repro.obs import trace as trc
 from repro.obs.snapshot import MetricsSnapshot
 from repro.obs.trace import TraceRecorder
@@ -580,19 +583,39 @@ class ServingEngine:
                    timesteps: Optional[int] = None,
                    cache: Optional[JitCache] = None,
                    bucket: Optional[int] = None):
-        """Pad to a bucket, run the jitted forward, host-sync the outputs.
-        ``bucket`` forces a specific pad bucket (canonical-bucket inference);
-        the default picks the smallest bucket that fits."""
+        """Pad to a bucket and copy it to the device (``snn.stage``), run
+        the jitted forward (``snn.dispatch``), wait for the outputs
+        (``snn.sync``).  ``bucket`` forces a specific pad bucket
+        (canonical-bucket inference); the default picks the smallest bucket
+        that fits.  A call made wholly inside a profiler trace folds its
+        skip fractions into the process counter (``obs.skip``)."""
         cache = cache if cache is not None else self.cache
         if bucket is None:
             bucket = bucket_for(len(frames), self.ecfg.buckets)
         elif bucket < len(frames):
             raise ValueError(
                 f"bucket={bucket} cannot hold a batch of {len(frames)}")
-        x = pad_frames(frames, bucket)
-        out = cache.run(x, self.ecfg.backend, timesteps=timesteps)
-        jax.block_until_ready(out.logits)
+        count = spans.tracing()
+        with spans.span(spans.STAGE, bucket=bucket):
+            x = jax.device_put(pad_frames(frames, bucket), cache.device)
+        with spans.span(spans.DISPATCH, bucket=bucket):
+            out = cache.run(x, self.ecfg.backend, timesteps=timesteps)
+        with spans.span(spans.SYNC, bucket=bucket):
+            jax.block_until_ready(out)
+        if count and spans.tracing():
+            self._fold_skip(obs_skip.counter(), out.skip_fractions, bucket,
+                            timesteps)
         return out
+
+    def _fold_skip(self, counter, fractions, bucket: int,
+                   timesteps: Optional[int]) -> None:
+        """Fold one ``bucket``-row call's skip fractions over ``timesteps``
+        (a chunk's length, or the served T) into ``counter``, without
+        waiting for them; a no-op off the pallas backend, whose outputs
+        carry no skip fractions."""
+        if fractions:
+            t = self.cfg.timesteps if timesteps is None else int(timesteps)
+            counter.fold(fractions, skip_cells(self.cfg, bucket, t))
 
     # -- chunked execution (EngineConfig.chunk_timesteps) --------------------
     def _zero_carry_row(self):
@@ -626,11 +649,20 @@ class ServingEngine:
         carried membrane state, execute the jitted ``snn_apply_chunk``, and
         host-sync.  Returns ``(ChunkOutputs, host batch carry)``."""
         cache = cache if cache is not None else self.cache
-        x = pad_frames([r.frame for r in grp], bucket)
-        carry = self._assemble_carry(grp, bucket)
-        out, new_carry = cache.run_chunk(x, carry, self.ecfg.backend, c)
-        jax.block_until_ready((out, new_carry))
-        return out, jax.tree_util.tree_map(np.asarray, new_carry)
+        count = spans.tracing()
+        with spans.span(spans.STAGE, bucket=bucket, chunk=c):
+            x, carry = jax.device_put(
+                (pad_frames([r.frame for r in grp], bucket),
+                 self._assemble_carry(grp, bucket)), cache.device)
+        with spans.span(spans.DISPATCH, bucket=bucket, chunk=c):
+            out, new_carry = cache.run_chunk(x, carry, self.ecfg.backend, c)
+        with spans.span(spans.SYNC, bucket=bucket, chunk=c):
+            jax.block_until_ready((out, new_carry))
+            host_carry = jax.tree_util.tree_map(np.asarray, new_carry)
+        if count and spans.tracing():
+            self._fold_skip(obs_skip.counter(), out.skip_fractions, bucket,
+                            c)
+        return out, host_carry
 
     def _finalize_chunked(self, r: Request) -> np.ndarray:
         """A chunk-served request's logits from its carried readout state —
@@ -1129,7 +1161,8 @@ class ServingEngine:
                     self._accumulate_chunk(
                         cout.timestep_counts, bucket - len(grp), tsteps,
                         offset=min(r.t_served for r in grp))
-                    self._note_skip(cout)
+                    self._fold_skip(self.metrics.skip, cout.skip_fractions,
+                                    bucket, tsteps)
                     self.trace.emit(trc.KIND_BATCH_DONE, t=finish, lane=lane,
                                     n=len(grp), svc=svc)
                     rows = self._carry_rows(new_carry, len(grp))
@@ -1162,7 +1195,8 @@ class ServingEngine:
                 else:
                     self._accumulate(out.timestep_counts, bucket - len(grp),
                                      tsteps)
-                    self._note_skip(out)
+                    self._fold_skip(self.metrics.skip, out.skip_fractions,
+                                    bucket, tsteps)
                     self.trace.emit(trc.KIND_BATCH_DONE, t=finish, lane=lane,
                                     n=len(grp), svc=svc)
                     logits = np.asarray(out.logits)
@@ -1197,15 +1231,6 @@ class ServingEngine:
         self.trace.emit(trc.KIND_DRAIN, t=clock.now(),
                         served=self.metrics.served)
         return self.summary()
-
-    def _note_skip(self, out) -> None:
-        """Fold one micro-batch's pallas skip-table sparsity (mean fraction
-        of (t, b, row-block) cells skipped across the fused layers) into the
-        metrics; a no-op on backends that don't compute skip tables."""
-        fracs = getattr(out, "skip_fractions", ())
-        if fracs:
-            self.metrics.note_skip_fraction(
-                float(np.mean([float(f) for f in fracs])))
 
     # -- threaded engine ----------------------------------------------------
     def _lane_worker(self, lane: int, cache: JitCache, clock,
@@ -1246,10 +1271,8 @@ class ServingEngine:
                     return self._exec_chunk(grp, bucket, c, cache=cache)
             else:
                 def exec_grp(grp=grp, bucket=bucket, tsteps=tsteps):
-                    x = pad_frames([r.frame for r in grp], bucket)
-                    out = cache.run(x, self.ecfg.backend, timesteps=tsteps)
-                    jax.block_until_ready(out.logits)
-                    return out
+                    return self._run_batch([r.frame for r in grp], tsteps,
+                                           cache=cache, bucket=bucket)
 
             try:
                 out, wall = self.dispatcher.execute(lane, exec_grp,
@@ -1274,25 +1297,21 @@ class ServingEngine:
             self.supervisor.beat(lane, clock.now())
             if chunked:
                 cout, carry = out
-                fracs = getattr(cout, "skip_fractions", ())
-                skip = (float(np.mean([float(f) for f in fracs]))
-                        if fracs else None)
                 completions.put((
                     "done", lane, grp, tsteps, widx, t_disp, clock.now(),
                     None,
                     [np.asarray(tc, dtype=np.float64)
                      for tc in cout.timestep_counts],
-                    bucket, wall, counts["retries"], skip, carry))
+                    bucket, wall, counts["retries"], cout.skip_fractions,
+                    carry))
             else:
-                fracs = getattr(out, "skip_fractions", ())
-                skip = (float(np.mean([float(f) for f in fracs]))
-                        if fracs else None)
                 completions.put((
                     "done", lane, grp, tsteps, widx, t_disp, clock.now(),
                     np.asarray(out.logits),
                     [np.asarray(tc, dtype=np.float64)
                      for tc in out.timestep_counts],
-                    bucket, wall, counts["retries"], skip, None))
+                    bucket, wall, counts["retries"], out.skip_fractions,
+                    None))
 
     def _warm_cache(self, cache: JitCache) -> None:
         """Compile + warm every executable a lane can dispatch — each
@@ -1492,14 +1511,13 @@ class ServingEngine:
                 self.supervisor.on_death(lane, clock.now())
             else:
                 (_, _, grp, tsteps, widx, t_disp, t_done, logits, tcs,
-                 bucket, wall, retries, skip, carry) = item
+                 bucket, wall, retries, fracs, carry) = item
                 self.metrics.retries += retries
                 self.metrics.note_resolved(len(grp))
                 self.dispatcher.commit(lane, t_disp, wall, len(grp))
                 # dispatch work, priced before t_served advances below
                 work = sum(self._eff_work(r) for r in grp)
-                if skip is not None:
-                    self.metrics.note_skip_fraction(skip)
+                self._fold_skip(self.metrics.skip, fracs, bucket, tsteps)
                 self.trace.emit(trc.KIND_BATCH_DONE, t=t_done, lane=lane,
                                 n=len(grp), svc=wall)
                 if carry is not None:     # chunked completion (tsteps = c)

@@ -15,6 +15,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.core.balance import balance_ratio
+from repro.obs.skip import SkipCounter, SkipReading
 
 __all__ = ["ServingMetrics", "percentile", "energy_per_image",
            "workload_residual"]
@@ -62,7 +63,6 @@ class ServingMetrics:
         "measured_balances": "_lock",
         "wall_balances": "_lock",
         "workload_residuals": "_lock",
-        "skip_fractions": "_lock",
         "recovery_s": "_lock",
         "restart_times": "_lock",
         "in_flight": "_lock",
@@ -83,10 +83,11 @@ class ServingMetrics:
     first_arrival: float = float("inf")
     last_finish: float = 0.0
     # workload-prediction observability: per-round APRC predicted-vs-measured
-    # share residuals (see workload_residual) and pallas skip-table sparsity
-    # (fraction of (t, b, row-block) cells skipped, one sample per batch)
+    # share residuals (see workload_residual) and the pallas skip-table
+    # counter (skipped and total (t, b, row-block) cells, one fold per batch;
+    # thread-safe on its own lock)
     workload_residuals: List[float] = field(default_factory=list)
-    skip_fractions: List[float] = field(default_factory=list)
+    skip: SkipCounter = field(default_factory=SkipCounter)
     # fault tolerance / graceful degradation (serving.supervisor + engine)
     restarts: int = 0                 # supervised lane restarts
     recovery_s: List[float] = field(default_factory=list)
@@ -155,12 +156,6 @@ class ServingMetrics:
         with self._lock:
             self.in_flight = max(0, self.in_flight - int(n))
 
-    def note_skip_fraction(self, frac: float) -> None:
-        """One micro-batch's pallas skip-table sparsity sample (fraction of
-        (t, b, row-block) cells whose receptive rows held zero spikes)."""
-        with self._lock:
-            self.skip_fractions.append(float(frac))
-
     def record_restart(self, recovery_s: float, at: float) -> None:
         """One supervised lane restart: ``recovery_s`` is death-to-serving
         time (the backoff delay plus scheduler latency), ``at`` the
@@ -189,10 +184,12 @@ class ServingMetrics:
         return span if span > 0 else 0.0
 
     def summary(self) -> Dict[str, float]:
+        skip = self.skip.read()
         with self._lock:
-            return self._summary_locked()
+            return self._summary_locked(skip)
 
-    def _summary_locked(self) -> Dict[str, float]:  # lint: holds(_lock)
+    def _summary_locked(  # lint: holds(_lock)
+            self, skip: SkipReading) -> Dict[str, float]:
         return {
             "served": self.served,
             "rounds": self.rounds,
@@ -234,9 +231,8 @@ class ServingMetrics:
             "workload_residual": float(np.mean(self.workload_residuals))
             if self.workload_residuals else 0.0,
             "residual_rounds": float(len(self.workload_residuals)),
-            "skip_sparsity": float(np.mean(self.skip_fractions))
-            if self.skip_fractions else 0.0,
-            "skip_batches": float(len(self.skip_fractions)),
+            "skip_sparsity": skip.share,
+            "skip_batches": float(skip.calls),
         }
 
     def snapshot_fields(self) -> Dict[str, float]:
@@ -244,6 +240,7 @@ class ServingMetrics:
         engine folds this into an ``obs.MetricsSnapshot``).  Percentiles
         are computed over a copy taken under the lock, so a mid-burst read
         never races an append."""
+        skip = self.skip.read()
         with self._lock:
             lat = list(self.latencies)
             return {
@@ -271,9 +268,8 @@ class ServingMetrics:
                 "workload_residual": float(np.mean(self.workload_residuals))
                 if self.workload_residuals else 0.0,
                 "residual_rounds": len(self.workload_residuals),
-                "skip_sparsity": float(np.mean(self.skip_fractions))
-                if self.skip_fractions else 0.0,
-                "skip_batches": len(self.skip_fractions),
+                "skip_sparsity": skip.share,
+                "skip_batches": skip.calls,
             }
 
 

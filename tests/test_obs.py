@@ -302,8 +302,8 @@ def test_summary_has_wall_and_in_flight(tiny):
 
 def test_skip_fraction_accumulation():
     m = ServingMetrics()
-    m.note_skip_fraction(0.5)
-    m.note_skip_fraction(1.0)
+    m.skip.fold((0.5,), (1,))
+    m.skip.fold((1.0,), (1,))
     s = m.summary()
     assert s["skip_batches"] == 2.0
     assert s["skip_sparsity"] == pytest.approx(0.75)
@@ -354,3 +354,138 @@ def test_library_default_is_quiet():
     # at WARNING via the repro root
     lg = obs_log.get_logger("somewhere")
     assert lg.getEffectiveLevel() >= 30
+
+
+# -- program spans and the skip-table counter (obs.spans, obs.skip) ----------
+
+from repro.core.snn_model import skip_cells  # noqa: E402
+from repro.obs import skip as obs_skip  # noqa: E402
+from repro.obs import spans as obs_spans  # noqa: E402
+
+
+def _seg_cfg():
+    # three conv layers, the last a non-firing readout: skip_fractions
+    # covers layers 1 (2 row blocks) and 2 (3 row blocks)
+    return dataclasses.replace(
+        get_snn("snn-seg"), input_hw=(12, 8), conv_channels=(4, 4, 1),
+        timesteps=2, num_spe_clusters=4)
+
+
+@pytest.fixture
+def skip_counter():
+    """The process counter, empty before and after a test."""
+    obs_skip.counter().reset()
+    yield obs_skip.counter()
+    obs_skip.counter().reset()
+
+
+def _xplane_spans(log_dir):
+    """(name, start, end, line) of every ``snn.*`` event in the trace."""
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(str(log_dir / "plugins/profile/*/*.xplane.pb"))[0]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("snn."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                (plane.name, line.name)))
+    return out
+
+
+def test_program_spans_nest_root_to_phase(tiny, tmp_path):
+    cfg, params = tiny
+    frames = np.stack(_frames(2, cfg))
+    inf = api.Session(cfg, api.ExecutionSpec(backend="batched"),
+                      params=params)
+    trn = api.Session(cfg, api.TrainSpec(backend="batched"), params=params)
+    inf.infer(frames)                         # compile outside the trace
+    trn.train_step(frames, np.array([1, 2]))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        inf.infer(frames)
+        trn.train_step(frames, np.array([3, 4]))
+    finally:
+        jax.profiler.stop_trace()
+    found = _xplane_spans(tmp_path)
+    assert {n for n, *_ in found} <= set(obs_spans.SPAN_NAMES)
+    roots = [s for s in found if s[0] in obs_spans.ROOTS]
+    assert sorted(r[0] for r in roots) == ["snn.infer", "snn.train_step"]
+    want = {"snn.infer": ["snn.stage", "snn.dispatch", "snn.sync"],
+            "snn.train_step": ["snn.stage", "snn.dispatch",
+                               "snn.update_engines", "snn.sync"]}
+    for name, lo, hi, line in roots:
+        kids = sorted((s for s in found if s[0] in obs_spans.PHASES
+                       and s[3] == line and lo <= s[1] and s[2] <= hi),
+                      key=lambda s: s[1])
+        assert [k[0] for k in kids] == want[name]
+    phases = [s for s in found if s[0] in obs_spans.PHASES]
+    assert all(any(r[3] == p[3] and r[1] <= p[1] and p[2] <= r[2]
+                   for r in roots) for p in phases)
+
+
+def test_skip_cells_hand_counts():
+    # APRC 3x3: layer 1 sees 14 rows -> 16 output rows, 2 blocks of 8;
+    # layer 2 sees 16 -> 18 rows, 3 blocks; the hoisted layer 0 has none
+    cfg = _seg_cfg()
+    assert skip_cells(cfg, 2, 2) == (2 * 2 * 2, 2 * 2 * 3)
+    assert skip_cells(cfg, 2, 2, hoist=False) == (8, 8, 12)
+    mnist = dataclasses.replace(_tiny_cfg(), conv_channels=(8, 8, 8))
+    assert skip_cells(mnist, 4, 3) == (3 * 4 * 2, 3 * 4 * 2)
+
+
+def test_skip_counter_off_adds_no_transfer(skip_counter):
+    cfg = _seg_cfg()
+    sess = api.Session(cfg, api.ExecutionSpec(backend="pallas"))
+    frames = np.stack(_frames(2, cfg))
+    sess.infer(frames)                        # compile
+    assert not obs_spans.tracing()            # no profiler: counter off
+    with jax.transfer_guard_device_to_host("disallow"):
+        sess.infer(frames)
+    assert skip_counter.read().calls == 0
+
+
+def test_skip_counter_folds_the_traced_calls_fractions(skip_counter,
+                                                       tmp_path):
+    cfg = _seg_cfg()
+    sess = api.Session(cfg, api.ExecutionSpec(backend="pallas"))
+    frames = np.stack(_frames(2, cfg))
+    frames[1, 4:] = 0.0                       # a quiet half: blocks to skip
+    sess.infer(frames)                        # compile, not counted
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        outs = [sess.infer(frames), sess.infer(frames[::-1].copy())]
+    finally:
+        jax.profiler.stop_trace()
+    sess.infer(frames)                        # after the trace: not counted
+    reading = skip_counter.read()
+    cells = skip_cells(cfg, 2, cfg.timesteps)
+    fr = np.array([[float(f) for f in o.skip_fractions] for o in outs])
+    assert reading.calls == 2 and reading.cells == tuple(2 * c for c in cells)
+    assert reading.skipped == pytest.approx(tuple(fr.sum(0) * cells))
+    assert reading.share == pytest.approx(
+        (fr * cells).sum() / (2 * sum(cells)))
+    assert 0.0 < reading.share < 1.0
+
+
+def test_skip_counter_rejects_mismatched_layers():
+    with pytest.raises(ValueError, match="layers"):
+        obs_skip.SkipCounter().fold((0.5,), (4, 4))
+
+
+def test_engine_skip_metrics_read_the_counter():
+    cfg = _seg_cfg()
+    spec = api.ServeSpec(backend="pallas", num_lanes=1, max_batch=2,
+                         buckets=(2,), keep_logits=False)
+    eng = api.Session(cfg, spec).engine()
+    for f in _frames(4, cfg, seed=3):
+        eng.submit(f, arrival=0.0)
+    eng.run()
+    snap = eng.snapshot()
+    reading = eng.metrics.skip.read()
+    assert snap.skip_batches == reading.calls == 2
+    assert reading.cells == tuple(2 * c for c in skip_cells(cfg, 2, 2))
+    assert snap.skip_sparsity == pytest.approx(reading.share)
+    assert eng.summary()["skip_batches"] == 2.0

@@ -128,3 +128,53 @@ def test_kernel_compiles_for_v5e(one_chip, case):
         assert groups > 1, f"{case}: CBWS channel groups collapsed to 1"
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _pallas_names(compiled) -> set:
+    """Names of the compiled program's Pallas custom calls, without XLA's
+    uniquifying ``.<n>`` suffix."""
+    import re
+    out = set()
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = line.strip().split(" = ")[0].lstrip("%")
+            out.add(re.sub(r"\.\d+$", "", name))
+    return out
+
+
+def test_model_kernels_carry_stable_layer_names(one_chip, monkeypatch):
+    """Forward and train step name each layer's kernels ``<kind>.l<k>``,
+    with no ``jvp``/``transpose`` prefix, in inference and in training."""
+    import dataclasses
+
+    import numpy as np
+
+    from repro.api import TrainSpec
+    from repro.core import init_snn, make_train_step, snn_apply
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                    sharding=one_chip)
+
+    for model, chans, dense in (("snn-mnist", (8, 8, 8), True),
+                                ("snn-seg", (8, 8, 1), False)):
+        cfg = dataclasses.replace(get_snn(model), input_hw=(8, 8),
+                                  conv_channels=chans, timesteps=2,
+                                  num_spe_clusters=4)
+        p = jax.tree.map(sds, init_snn(jax.random.PRNGKey(0), cfg))
+        x = jax.ShapeDtypeStruct((2, 8, 8, cfg.input_channels), jnp.float32,
+                                 sharding=one_chip)
+        fwd = jax.jit(lambda p, x, cfg=cfg: snn_apply(p, x, cfg,
+                                                      backend="pallas"))
+        last = "conv_lif_fwd.l2" if dense else "conv.l2"
+        assert _pallas_names(fwd.lower(p, x).compile()) == {
+            "conv.l0", "conv_lif_fwd.l1", last}
+        if not dense:
+            continue
+        step = jax.jit(make_train_step(cfg, spec=TrainSpec(backend="pallas")))
+        y = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+        assert _pallas_names(step.lower(p, p, x, y).compile()) == {
+            "conv.l0", "conv_lif_fwd.l1", "conv_lif_fwd.l2", "lif_bwd.l1",
+            "lif_bwd.l2", "conv_grad_input.l1", "conv_grad_input.l2"}
