@@ -20,14 +20,28 @@ i.e. per element the HBM round trips drop from ~5T to ~T+2 — the fusion of
 Sommer et al. (arXiv 2203.12437, accumulate-into-neuron) combined with
 FireFly v2's (arXiv 2309.16158) spatiotemporal (T x B) batching.
 
-Grid: ``(B, n_row_blocks, num_groups, T)`` — batch x row-block x CBWS
-channel lane x timestep.  Each cell holds one timestep's blocks, so VMEM
-use does not grow with T (a whole-T block overflowed VMEM at snn-seg
-widths).  The spike-count skip table ``counts[t, b, i]`` (SMEM) covers the
-full spatio-temporal workload (paper Fig. 2): a timestep whose receptive
-rows carry no spikes skips all R*R matmuls and integrates bias only.
-Outputs and membranes use the kernel layout of
-``spiking_conv.to_block_layout`` (see that module's doc for why).
+Grid: ``(B, n_row_blocks, T)`` — batch x row-block x timestep.  Each cell
+holds one timestep's blocks, so VMEM use does not grow with T (a whole-T
+block overflowed VMEM at snn-seg widths), and computes **every CBWS
+channel group** of its (b, i, t): the weight and bias blocks hold all
+Cout channels, the group axis leads the membrane and output blocks whole,
+and each of the R*R tap tiles is built once and fed to one
+``(Cout, Cin) x (rows*W, Cin)^T`` MXU dot for all groups.  An earlier
+grid ``(B, n_row_blocks, num_groups, T)`` gave each group its own cell;
+on a TensorCore those cells run one after another, and each re-fetched
+the same halo block and rebuilt the same tap tiles for 1-4 output rows.
+Measured on a v5e, a cell cost 4-6 ns per pixel whatever its group's
+channel count (1-4), Cin (8-32) or pixel count (256-1,360): the cost
+followed the pixels, not the channels, and an all-groups cell costs
+about what one group's did.  A loop of one dot per group over the shared
+tile was slower than the old grid.  The CBWS groups (``num_groups``, the
+weights' permutation, the output layout) are unchanged.
+
+The spike-count skip table ``counts[t, b, i]`` (SMEM) covers the full
+spatio-temporal workload (paper Fig. 2): a timestep whose receptive rows
+carry no spikes skips all R*R matmuls and integrates bias only.  Outputs
+and membranes use the kernel layout of ``spiking_conv.to_block_layout``
+(see that module's doc for why).
 
 Sequencing caveat: the input spike train for all T must be known, so this
 kernel runs in the **layer-by-layer** (time-batched) execution order of
@@ -81,7 +95,7 @@ from repro.core.surrogate import surrogate_grad
 from repro.kernels.spiking_conv import (conv_grad_input_pallas,
                                         conv_grad_input_xla,
                                         conv_grad_weights_xla,
-                                        from_block_layout, group_taps,
+                                        from_block_layout,
                                         kernel_name, row_block_counts,
                                         tap_gemm, to_block_layout)
 
@@ -97,16 +111,23 @@ def _make_kernel(r: int, block_rows: int, w_out: int, n_blocks: int,
         *maybe_u_ref, v_scr = rest
         b = pl.program_id(0)
         i = pl.program_id(1)
-        t = pl.program_id(3)
+        t = pl.program_id(2)
         n_batch = pl.num_programs(0)
-        bias = b_ref[...].astype(jnp.float32)      # (Cout_blk, 1)
+        bias = b_ref[...].astype(jnp.float32)      # (Cout, 1)
+        # channel group g is rows g*Cout/G .. (g+1)*Cout/G of the (Cout, M)
+        # membrane, and leading slice g of the membrane and output blocks
+        n_groups, cout_blk = v0_ref.shape[:2]
+        groups = [slice(g * cout_blk, (g + 1) * cout_blk)
+                  for g in range(n_groups)]
 
         @pl.when(t == 0)
         def _load_v0():
-            v_scr[...] = v0_ref[...].astype(jnp.float32)
+            for g, rows in enumerate(groups):
+                v_scr[rows, :] = v0_ref[g].astype(jnp.float32)
 
         def compute():
-            # halo block for timestep t: (block_rows+R-1, W_pad, Cin)
+            # halo block for timestep t: (block_rows+R-1, W_pad, Cin); one
+            # (Cout, Cin) dot per tap serves every channel group
             return tap_gemm(x_ref, w_ref, r, block_rows, w_out) + bias
 
         def skip():
@@ -115,17 +136,19 @@ def _make_kernel(r: int, block_rows: int, w_out: int, n_blocks: int,
 
         count = counts_ref[(t * n_batch + b) * n_blocks + i]
         v = v_scr[...] + jax.lax.cond(count == 0, skip, compute)  # Eq. (1)+(2)
-        if save_u:
-            # pre-reset membrane: the surrogate's backward residual
-            maybe_u_ref[0][...] = v.astype(maybe_u_ref[0].dtype)
         s = (v >= v_th).astype(jnp.float32)        # Eq. (3): fire
-        v = v - v_th * s                           # reset by subtraction
-        s_ref[...] = s.astype(s_ref.dtype)
-        v_scr[...] = v
+        v_next = v - v_th * s                      # reset by subtraction
+        v_scr[...] = v_next
+        for g, rows in enumerate(groups):
+            if save_u:
+                # pre-reset membrane: the surrogate's backward residual
+                maybe_u_ref[0][g] = v[rows].astype(maybe_u_ref[0].dtype)
+            s_ref[g] = s[rows].astype(s_ref.dtype)
 
-        @pl.when(t == pl.num_programs(3) - 1)
+        @pl.when(t == pl.num_programs(2) - 1)
         def _store_v():
-            v_ref[...] = v.astype(v_ref.dtype)
+            for g, rows in enumerate(groups):
+                v_ref[g] = v_next[rows].astype(v_ref.dtype)
 
     return kernel
 
@@ -163,17 +186,18 @@ def _fused_call(spikes, v0, w, bias, *, v_th, aprc, block_rows, num_groups,
     vp = jnp.zeros((B, e_h_pad, e_w, Cout), v0.dtype)
     vp = jax.lax.dynamic_update_slice(vp, v0, (0, 0, 0, 0))
 
-    # grid (B, row-block, channel group, T): T is the innermost,
-    # sequential axis — one timestep's blocks per cell, the membrane
-    # carried across it in VMEM scratch.  Outputs and membranes are in
+    # grid (B, row-block, T): T is the innermost, sequential axis — one
+    # timestep's blocks per cell, the membrane carried across it in VMEM
+    # scratch.  Every cell computes all G channel groups of its (b, i, t):
+    # the group axis leads each block whole.  Outputs and membranes are in
     # spiking_conv.to_block_layout: (G, [T,] B, n_blocks, Cout/G, M)
     m = block_rows * e_w
     seq_spec = pl.BlockSpec(
-        (pl.squeezed, pl.squeezed, pl.squeezed, pl.squeezed, cout_blk, m),
-        lambda b, i, g, t: (g, t, b, i, 0, 0))
+        (num_groups, pl.squeezed, pl.squeezed, pl.squeezed, cout_blk, m),
+        lambda b, i, t: (0, t, b, i, 0, 0))
     mem_spec = pl.BlockSpec(
-        (pl.squeezed, pl.squeezed, pl.squeezed, cout_blk, m),
-        lambda b, i, g, t: (g, b, i, 0, 0))
+        (num_groups, pl.squeezed, pl.squeezed, cout_blk, m),
+        lambda b, i, t: (0, b, i, 0, 0))
     # the optional pre-reset membrane output (backward residual) rides as a
     # concatenated extra: both lists stay statically resolvable for the
     # pallas-consistency analysis rule
@@ -195,27 +219,27 @@ def _fused_call(spikes, v0, w, bias, *, v_th, aprc, block_rows, num_groups,
     outs = pl.pallas_call(
         kernel,
         name=name,
-        grid=(B, n_blocks, num_groups, T),
+        grid=(B, n_blocks, T),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                  # counts
             # halo input block per (t, b, i): element offsets (pl.Element)
             pl.BlockSpec((pl.squeezed, pl.squeezed, pl.Element(halo_rows),
                           pl.Element(w_pad), pl.Element(Cin)),
-                         lambda b, i, g, t: (t, b, i * block_rows, 0, 0)),
-            pl.BlockSpec((pl.squeezed, R, R, cout_blk, Cin),
-                         lambda b, i, g, t: (g, 0, 0, 0, 0)),
-            pl.BlockSpec((pl.squeezed, cout_blk, 1),
-                         lambda b, i, g, t: (g, 0, 0)),
+                         lambda b, i, t: (t, b, i * block_rows, 0, 0)),
+            # all taps, constant index map: fetched once per call.  The
+            # (R, R, Cout, Cin) layout is group_taps' (G, R, R, Cout/G, Cin)
+            # with the G groups stacked back into Cout
+            pl.BlockSpec((R, R, Cout, Cin), lambda b, i, t: (0, 0, 0, 0)),
+            pl.BlockSpec((Cout, 1), lambda b, i, t: (0, 0)),
             mem_spec,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((cout_blk, m), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((Cout, m), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary")),
+            "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(counts, x, group_taps(w, num_groups),
-      bias.reshape(num_groups, cout_blk, 1),
+    )(counts, x, w.transpose(0, 1, 3, 2), bias.reshape(Cout, 1),
       to_block_layout(vp, num_groups, block_rows))
     outs = [from_block_layout(o, e_w) for o in outs]
     if save_u:
@@ -336,10 +360,11 @@ def lif_bwd_pallas(
     block_rows: int = 8, num_groups: int = 4, interpret: bool,
     name: str | None = None,
 ):
-    """Pallas reverse-time LIF backward: the same (B, row-block,
-    channel-group) grid as the forward kernel plus an innermost sequential
-    T axis walked backward, the running current-cotangent carried across
-    it in VMEM scratch.
+    """Pallas reverse-time LIF backward: a (B, row-block, channel-group)
+    grid plus an innermost sequential T axis walked backward, the running
+    current-cotangent carried across it in VMEM scratch.  It reads and
+    writes the forward's block layout; being elementwise, it rebuilds no
+    tiles per group.
 
     Returns ``(lam: (T, B, E_h, E_w, Cout) f32, dv0: (B, E_h, E_w, Cout))``.
     """

@@ -7,6 +7,8 @@ import pytest
 from repro.core import cbws
 from repro.kernels import ops, ref
 from repro.kernels.spiking_conv import row_block_counts
+from repro.kernels.spiking_conv_lif import (spiking_conv_lif_fwd_pallas,
+                                            spiking_conv_lif_pallas)
 
 # Interpret mode runs the grid in a Python loop — keep shapes small so the
 # default (non-slow) suite stays fast while covering every structural case.
@@ -130,6 +132,8 @@ FUSED_CASES = [
     (3, 2, 8, 8, 3, 8, 3, True, 4, 2),
     (2, 1, 7, 9, 2, 6, 3, True, 4, 3),    # non-block-divisible rows
     (2, 2, 6, 6, 4, 6, 3, False, 4, 2),   # same-pad (APRC off)
+    (2, 2, 8, 8, 4, 8, 3, True, 4, 8),    # the models' 8 CBWS groups, 1 ch
+    (2, 1, 9, 8, 4, 16, 3, True, 4, 8),   # 8 groups of 2 channels, ragged
 ]
 
 
@@ -196,3 +200,53 @@ def test_spiking_conv_lif_single_step_matches_two_kernel_path():
                                np.asarray(s2.reshape(s[0].shape)), atol=1e-5)
     np.testing.assert_allclose(np.asarray(v),
                                np.asarray(v2.reshape(v.shape)), atol=1e-5)
+
+
+GROUPS8_CASES = [c for c in FUSED_CASES if c[-1] == 8]
+
+
+@pytest.mark.parametrize("case", GROUPS8_CASES)
+def test_fused_forward_save_u_matches_forward(case):
+    """The training forward (saves the pre-reset membrane ``u``) emits the
+    inference forward's spikes and final membrane, and ``u`` fires exactly
+    where ``s`` does, at the models' 8 CBWS groups."""
+    _, _, _, _, _, _, _, aprc, br, g = case
+    spikes, v0, w, bias = _fused_inputs(case, 0.18)
+    kw = dict(v_th=1.0, aprc=aprc, block_rows=br, num_groups=g,
+              interpret=True)
+    s, v = spiking_conv_lif_pallas(spikes, v0, w, bias, **kw)
+    s2, v2, u = spiking_conv_lif_fwd_pallas(spikes, v0, w, bias, **kw)
+    np.testing.assert_array_equal(np.asarray(s2), np.asarray(s))
+    np.testing.assert_array_equal(np.asarray(v2), np.asarray(v))
+    np.testing.assert_array_equal(np.asarray(u >= 1.0).astype(np.float32),
+                                  np.asarray(s))
+    # the last step's reset: v_final = u_{T-1} - v_th * s_{T-1}
+    np.testing.assert_allclose(np.asarray(v), np.asarray(u[-1] - s[-1]),
+                               atol=1e-6)
+
+
+def _pallas_grids(jaxpr) -> list:
+    """Grids of every ``pallas_call`` in ``jaxpr``, nested jaxprs included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for p in eqn.params.values():
+            sub = getattr(p, "jaxpr", p)
+            if hasattr(sub, "eqns"):
+                grids.extend(_pallas_grids(sub))
+    return grids
+
+
+@pytest.mark.parametrize("save_u", [False, True])
+def test_fused_forward_grid_has_no_group_axis(save_u):
+    """The fused forward's grid is (B, n_blocks, T): every cell computes all
+    CBWS channel groups of its (image, row block, timestep)."""
+    case = FUSED_CASES[-1]                      # T=2, B=1, 11 rows / 4, G=8
+    t, b, h, *_ = case
+    spikes, v0, w, bias = _fused_inputs(case, 0.18)
+    fn = spiking_conv_lif_fwd_pallas if save_u else spiking_conv_lif_pallas
+    jaxpr = jax.make_jaxpr(lambda *a: fn(
+        *a, block_rows=4, num_groups=8, interpret=True))(spikes, v0, w, bias)
+    n_blocks = -(-(h + 2) // 4)
+    assert _pallas_grids(jaxpr.jaxpr) == [(b, n_blocks, t)]

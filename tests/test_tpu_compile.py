@@ -5,7 +5,10 @@ described rather than attached, so Mosaic's refusals (block shapes that
 are neither whole dims nor (8, 128) multiples, unsupported primitives,
 VMEM or HBM overflow) show up here instead of on the chip.  Widths are the
 paper's (``snn-mnist`` at the serving batch, ``snn-seg`` at batch 8) with
-the CBWS channel-group grid axis at its real size (> 1).
+the CBWS channel-group count at its real size (> 1).  For the fused forward
+the groups live inside the cell, not on the grid: each cell's blocks hold
+all of them, so the widest layer (snn-seg layer 4, 12 row blocks of 8x170
+pixels) proves that those blocks fit v5e VMEM.
 
 The topology is described inside a module fixture — never at import — so
 every xdist worker collects the same tests and only the worker running
@@ -113,6 +116,7 @@ CASES = {
     "mnist-lif-fused": _lif_fused,
     "seg-fused-forward": lambda s: _fused(s, "snn-seg", 2, False),
     "seg-fused-forward-save-u": lambda s: _fused(s, "snn-seg", 3, True),
+    "seg-fused-forward-save-u-l4": lambda s: _fused(s, "snn-seg", 4, True),
     "seg-conv-grad-input": lambda s: _grad_input(s, "snn-seg", 2),
     "seg-lif-bwd": lambda s: _lif_bwd(s, "snn-seg", 3),
 }
